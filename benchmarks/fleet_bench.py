@@ -113,6 +113,7 @@ def run(smoke: bool = False) -> dict:
     from repro.kernels import blocking, ops
     from repro.lifetime import DriftConfig, SchedulePolicy
     from repro.models import vision
+    from repro.platform import pallas_interpret
     from repro.serving import FleetEngine, FleetSweepPolicy
     from repro.variation import VariationConfig
 
@@ -129,7 +130,8 @@ def run(smoke: bool = False) -> dict:
                                     (8 if smoke else 16, 32, 32, 3))
 
     results = {"smoke": smoke, "microbatch": mb, "hw": 32,
-               "repeats": repeats, "interpret": True,
+               "repeats": repeats,
+               "interpret": pallas_interpret(),
                "variation_profile": VARIATION_PROFILE,
                "drift_profile": DRIFT_PROFILE}
 

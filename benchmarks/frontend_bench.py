@@ -110,7 +110,7 @@ def legacy_double_conv_step(fe_cfg, block_n: int = PREFIX_BLOCK_N):
         o = ops.p2m_conv(frames, wq, theta, key,
                          kernel=pcfg.kernel_size, stride=pcfg.stride,
                          pixel_params=pcfg.pixel, mtj_params=pcfg.mtj,
-                         interpret=fe_cfg.interpret, block_n=block_n)
+                         block_n=block_n)
         return o, {"theta": theta,
                    **_v_conv_stats(pixel.conv_voltage(u, theta, pcfg.pixel))}
 
@@ -130,6 +130,7 @@ def run(smoke: bool = False) -> dict:
     from repro.core import mtj as mtj_model
     from repro.core import p2m
     from repro.kernels import autotune, blocking, ops
+    from repro.platform import pallas_interpret
 
     # the serving-shaped batch (16 frames) is kept in smoke mode too — the
     # speedup-vs-prefix and stream-vs-analog numbers are only meaningful at
@@ -153,7 +154,7 @@ def run(smoke: bool = False) -> dict:
         repeats=2 if smoke else 4)
 
     results = {"batch": batch, "hw": 32, "repeats": repeats,
-               "interpret": True, "backends": {},
+               "interpret": pallas_interpret(), "backends": {},
                "autotune": {"choice": choice.to_json(),
                             "report": tune_report}}
 
@@ -224,7 +225,7 @@ def run(smoke: bool = False) -> dict:
         wall = ms["prefix_double_conv" if block_n == PREFIX_BLOCK_N
                   else "prefix_same_tile"]
         census = hlo_analysis.matmul_stats(compiled.as_text())
-        cost = analysis_census.compile_cost(compiled)
+        cost = compiled.cost_analysis()
         results[tag] = {
             "wall_ms": wall,
             "frames_per_s": batch / (wall / 1e3),

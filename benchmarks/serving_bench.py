@@ -236,7 +236,7 @@ def _autotune_point(cfg, params, pool, mb: int, repeats: int) -> Dict:
         pool[:mb], wq, params["p2m"]["v_th"], jax.random.PRNGKey(3),
         kernel=pcfg.kernel_size, stride=pcfg.stride,
         pixel_params=pcfg.pixel, mtj_params=pcfg.mtj,
-        interpret=True, repeats=repeats, store=True)
+        repeats=repeats, store=True)
     return choice.to_json()
 
 

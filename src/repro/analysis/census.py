@@ -95,10 +95,10 @@ def _classify_prim(name: str) -> Optional[str]:
 
 
 def _sub_jaxprs(value):
-    import jax
-    if isinstance(value, jax.core.Jaxpr):
+    from jax.extend import core as jex_core
+    if isinstance(value, jex_core.Jaxpr):
         yield value
-    elif isinstance(value, jax.core.ClosedJaxpr):
+    elif isinstance(value, jex_core.ClosedJaxpr):
         yield value.jaxpr
     elif isinstance(value, (list, tuple)):
         for v in value:
@@ -159,14 +159,6 @@ def jaxpr_census(fn: Callable, *args, **kwargs) -> Dict[str, object]:
 
 
 # --- HLO census -------------------------------------------------------------
-
-def compile_cost(compiled) -> Dict:
-    """Normalized ``compiled.cost_analysis()`` (list- or dict-shaped)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
 
 def hlo_census(jitted_fn, *args, **kwargs) -> Tuple[Dict, object]:
     """Compile ``jitted_fn`` at the example arguments (no execution) and
@@ -522,7 +514,7 @@ def frontend_step_info(batch: int = FRONTEND_BATCH) -> Dict[str, Dict]:
     for name, fn, args in _frontend_entries(batch):
         mode = name.split(".", 1)[1]
         census, compiled = hlo_census(fn, *args)
-        out[mode] = {"census": census, "cost": compile_cost(compiled),
+        out[mode] = {"census": census, "cost": compiled.cost_analysis(),
                      "step": fn, "args": args}
     return out
 

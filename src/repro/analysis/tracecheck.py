@@ -21,8 +21,9 @@ On failure the assert names the offending argument by its jit debug path::
 Implementation: while a :class:`TraceRecorder` is active, every fresh jit
 trace (a miss of the C++ fast-path cache) is recorded with the function
 identity, the jit debug-info argument names, and the input avals. The hook
-point is ``jax._src.pjit._create_pjit_jaxpr`` — the single choke point every
-pjit trace funnels through in jax 0.4.x; the recorder restores the original
+point is ``jax._src.interpreters.partial_eval.trace_to_jaxpr`` — the cached
+function every jit trace funnels through; a call counts as a fresh trace
+when it raised the cache's miss count. The recorder restores the original
 on exit and is reentrant (nested captures share one patch).
 """
 from __future__ import annotations
@@ -32,7 +33,7 @@ import dataclasses
 import threading
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
-import jax._src.pjit as _pjit
+from jax._src.interpreters import partial_eval as _pe
 
 
 class RetraceError(AssertionError):
@@ -111,38 +112,39 @@ def _install() -> None:
     global _ORIG
     if _ORIG is not None:
         return
-    _ORIG = _pjit._create_pjit_jaxpr
+    _ORIG = orig = _pe.trace_to_jaxpr
 
-    def recording_create_pjit_jaxpr(fun, *args):
-        # args = (in_type, attr_token, debug_info, result_paths, ignore_key)
+    def recording_trace_to_jaxpr(fun, in_avals, debug_info, *rest):
+        misses = orig.cache_info().misses
+        out = orig(fun, in_avals, debug_info, *rest)
+        # a hit runs nothing; a miss traced ``fun`` (and any jit nested in
+        # it, which recorded itself first)
+        if orig.cache_info().misses == misses:
+            return out
         try:
-            dbg = args[2]
-            ev = TraceEvent(fun=fun.f,
-                            name=getattr(dbg, "func_src_info", None)
-                            or getattr(fun.f, "__name__", repr(fun.f)),
-                            arg_names=tuple(getattr(dbg, "arg_names", ())
-                                            or ()),
-                            avals=tuple(args[0]))
-            for rec in list(_ACTIVE):
-                rec._record(ev)
-        except RetraceError:        # no_retrace enforcement must surface
-            raise
+            ev = TraceEvent(fun=fun,
+                            name=getattr(debug_info, "func_src_info", None)
+                            or getattr(fun, "__name__", repr(fun)),
+                            arg_names=tuple(getattr(debug_info, "arg_names",
+                                                    ()) or ()),
+                            avals=tuple(in_avals))
         except Exception:           # never let telemetry break tracing
-            pass
-        return _ORIG(fun, *args)
+            return out
+        for rec in list(_ACTIVE):
+            rec._record(ev)         # no_retrace enforcement may raise here
+        return out
 
-    # pjit internals call attributes of this symbol (cache_clear /
-    # evict_function, e.g. from jit.clear_cache and atexit) — forward them
-    for attr in ("cache_clear", "evict_function"):
-        if hasattr(_ORIG, attr):
-            setattr(recording_create_pjit_jaxpr, attr, getattr(_ORIG, attr))
-    _pjit._create_pjit_jaxpr = recording_create_pjit_jaxpr
+    # jit internals call attributes of this symbol (cache_clear /
+    # evict_weakref, e.g. from jit.clear_cache) — forward them
+    for attr in ("cache_clear", "cache_info", "evict_weakref"):
+        setattr(recording_trace_to_jaxpr, attr, getattr(orig, attr))
+    _pe.trace_to_jaxpr = recording_trace_to_jaxpr
 
 
 def _uninstall() -> None:
     global _ORIG
     if _ORIG is not None and not _ACTIVE:
-        _pjit._create_pjit_jaxpr = _ORIG
+        _pe.trace_to_jaxpr = _ORIG
         _ORIG = None
 
 

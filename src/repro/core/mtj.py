@@ -159,9 +159,11 @@ def bernoulli_from_bits(bits: jax.Array, q: jax.Array) -> jax.Array:
     [0, 1), falls below q. The SINGLE source of the draw expression for the
     Pallas kernels, their oracles (kernels/ref.py), and the legacy baseline
     — kernel<->oracle bit-parity rests on all of them tracing this one
-    function. Returns float {0,1}.
+    function. Returns float {0,1}. The word widens through int32 (exact)
+    because the TPU kernel compiler has no unsigned-to-float conversion.
     """
-    return ((bits.astype(jnp.float32) * _DRAW_SCALE) < q).astype(jnp.float32)
+    u = bits.astype(jnp.int32).astype(jnp.float32)
+    return ((u * _DRAW_SCALE) < q).astype(jnp.float32)
 
 
 # --- multi-MTJ majority statistics (Fig. 5) ---------------------------------

@@ -103,7 +103,6 @@ class FrontendConfig:
     p2m: p2m.P2MConfig = p2m.P2MConfig()
     backend: str = "analog"
     global_shutter: bool = True   # run burst_read + reset accounting
-    interpret: bool = True        # Pallas interpret mode (CPU); False on TPU
     # device-variation handle (repro/variation, DESIGN.md §7): when set, the
     # frontend simulates THIS sampled chip — the device/pallas backends
     # thread its mismatch maps through the physics and the analog backend
@@ -131,7 +130,7 @@ class FrontendConfig:
     # activation grid) and folds dequant into the voltage-map epilogue — the
     # device chain after the MAC is the same kernel code either way.
     precision: Optional[str] = None
-    # real TPUs only (interpret=False): generate the fused path's draw words
+    # real TPUs only (compiled Pallas): generate the fused path's draw words
     # in-kernel (pltpu.prng_random_bits seeded per (key, block)) instead of
     # streaming ops.draw_bits from HBM. Interpret mode keeps the hash-word
     # oracle so CPU validation stays bit-exact vs kernels/ref.py.

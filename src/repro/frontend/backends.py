@@ -200,7 +200,8 @@ def device_backend(cfg: FrontendConfig, params: dict, images: jax.Array,
 @register_backend("pallas", stateful=True)
 def pallas_backend(cfg: FrontendConfig, params: dict, images: jax.Array,
                    key: Optional[jax.Array]) -> Tuple[jax.Array, Dict]:
-    """Single-pass Pallas TPU kernel pipeline (interpret mode on CPU).
+    """Single-pass Pallas TPU kernel pipeline (compiled on a TPU,
+    interpreted elsewhere — ``repro.platform.pallas_interpret``).
 
     The patch matmul runs exactly once, in kernel A, which also emits the
     per-block partial reductions for the *global* Hoyer threshold; a scalar
@@ -227,7 +228,7 @@ def pallas_backend(cfg: FrontendConfig, params: dict, images: jax.Array,
     wq = p2m.quantize_weights(params["w"], pcfg.weight_bits)
     kw = dict(kernel=pcfg.kernel_size, stride=pcfg.stride, chan=chan,
               pixel_params=pcfg.pixel, mtj_params=pcfg.mtj,
-              interpret=cfg.interpret, block_n=cfg.block_n,
+              block_n=cfg.block_n,
               block_n_elem=cfg.block_n_elem, precision=cfg.precision)
     carry = params.get("theta_carry")
     if carry is not None:

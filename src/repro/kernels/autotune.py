@@ -26,9 +26,10 @@ per-call search:
 
 Heuristic default: the largest whole-row block that keeps a single MXU pass
 per step without collapsing the grid to one step (``block_n = min(n // 2,
-4096)``) — on the interpret-mode CPU target fewer grid steps win, and on a
-real TPU the same shape keeps VMEM per step at ``block_n * (K + 2C)`` floats
-(~1.7 MB at the paper's geometry), comfortably under budget.
+4096)``), and a whole-N target for the fused kernel. Every choice is a
+TARGET: the kernels cap each grid step by their VMEM budget
+(``blocking.implicit_block`` / ``elem_rows_cap``), so no table entry can
+ask the TPU compiler for more scoped VMEM than it grants.
 """
 from __future__ import annotations
 
@@ -48,14 +49,13 @@ class TileChoice:
     ``block_n`` tiles the EXACT path's kernel A; the fused streaming kernel
     has its own ``block_n_fused`` because its constraints differ — the exact
     path wants >= 2 grid steps (each step's matmul stays at or below the
-    ideal-conv flop count), while the fused kernel has no such pressure and
-    on the interpret-mode target a single step minimizes the dominant
-    grid-loop overhead (on a real TPU the VMEM budget caps it instead —
-    that is what the measured search is for).
+    ideal-conv flop count), while the fused kernel has no such pressure:
+    its default target is the whole microbatch, which the kernel's VMEM
+    cap then splits into the largest steps that fit.
     """
     block_n: int          # kernel-A patch-row block target (implicit im2col)
     block_n_elem: int     # kernel-B elementwise row-block cap
-    block_n_fused: int = 0  # fused-kernel patch-row block (0 = whole N)
+    block_n_fused: int = 0  # fused-kernel patch-row target (0 = whole N)
     fused: bool = True    # stream with the single fused kernel
     precision: str = "f32"  # matmul precision the tuner picked (f32 | int8)
 
@@ -86,7 +86,8 @@ def default_choice(n: int, k_eff: int, c_out: int) -> TileChoice:
 
     ``block_n = n // 2`` keeps the exact path's kernel A at >= 2 grid steps
     (per-step matmul flops <= the ideal-conv census) while minimizing the
-    interpret-mode grid overhead; the fused kernel defaults to one step.
+    interpret-mode grid overhead; the fused kernel targets the whole N
+    (the kernels' VMEM cap sets the real step).
     """
     block_n = max(min(n // 2, 4096), 1)
     return TileChoice(block_n=block_n,
@@ -197,9 +198,9 @@ def resolve_fleet_fused(chips_in_batch: int, n: int, k_eff: int, c_out: int,
 def save_table(path: str) -> None:
     """Persist the in-process table as JSON ({"n,k,c": {...}}).
 
-    A ``"_meta"`` entry (repro.obs.export.bench_meta) stamps the backend /
-    jax version the timings were measured on — a table tuned elsewhere is
-    still loadable, but the mismatch is visible in the file.
+    A ``"_meta"`` entry (repro.obs.export.bench_meta) stamps the backend,
+    device kind and jax version the timings were measured on;
+    ``load_table`` refuses the table anywhere else.
     """
     from repro.obs.export import bench_meta
     table = {",".join(map(str, k)): v.to_json()
@@ -212,10 +213,21 @@ def save_table(path: str) -> None:
 def load_table(path: str) -> int:
     """Merge a persisted table into the process; returns entries loaded.
 
-    Keys starting with ``"_"`` (the ``"_meta"`` stamp) are skipped.
+    Tiles measured on one device say nothing about another (a table tuned
+    on the CPU interpreter would size steps for a machine without VMEM), so
+    a table whose ``"_meta"`` stamp names another backend or device kind —
+    or that carries no stamp — raises ``ValueError``.
     """
+    import jax
     with open(path) as f:
         raw = json.load(f)
+    meta = raw.get("_meta", {})
+    here = {"backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind}
+    theirs = {k: meta.get(k, "unstamped") for k in here}
+    if theirs != here:
+        raise ValueError(f"tile table {path} was tuned on {theirs}, not on "
+                         f"this {here}: re-run the search here")
     n = 0
     for k, v in raw.items():
         if k.startswith("_"):
@@ -264,7 +276,7 @@ def _best_of(fn: Callable[[], None], repeats: int) -> float:
 def autotune_frontend(images, w, v_th, key, *, kernel: int = 3,
                       stride: int = 2, chan=None,
                       pixel_params=None, mtj_params=None,
-                      interpret: bool = True, repeats: int = 3,
+                      interpret: Optional[bool] = None, repeats: int = 3,
                       store: bool = True):
     """Measure the candidate grid for this call shape; return
     ``(TileChoice, report)`` and (by default) record the winner.

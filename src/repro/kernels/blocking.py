@@ -94,6 +94,55 @@ def a_block_geometry(b: int, ho: int, wo: int, block_n: int
     return bb, block_oh
 
 
+# --- VMEM budget of one grid step -------------------------------------------
+#
+# A TPU kernel's per-step buffers live in scoped VMEM, whose default limit is
+# 16 MiB on a v5e; Mosaic refuses a kernel whose step needs more. Arrays are
+# tiled (8 sublanes x 128 lanes) there, so every width below is padded to
+# that tile. The frontend kernels keep their estimate within 3/4 of the
+# limit. The estimate is an upper bound calibrated by compiling for a
+# described v5e (tests/test_tpu_compile.py): at 32x32x3 frames, K=27, C=32
+# the compiler's own scoped allocation is ~4.4 KiB per patch row for the
+# fused kernel and ~4.3 KiB for kernel B, against 7.1 and 4.0 KiB here.
+VMEM_BUDGET = 12 * 2 ** 20
+
+
+def _tiled(n: int, tile: int) -> int:
+    return -(-n // tile) * tile
+
+
+def row_vmem_bytes(k_eff: int, c_out: int) -> int:
+    """VMEM one patch row costs a grid step: double-buffered f32 output,
+    uint16 draw words and f32 u, the K-wide patch row, and three f32
+    epilogue temporaries on 2C lanes (the rest stays in vector registers)."""
+    return (2 * (4 + 2 + 4) * _tiled(c_out, 128)
+            + 4 * (_tiled(k_eff, 128) + 3 * _tiled(2 * c_out, 128)))
+
+
+def elem_rows_cap(c_out: int) -> int:
+    """Most rows an elementwise kernel-B step may hold in VMEM."""
+    return max(VMEM_BUDGET // row_vmem_bytes(0, c_out), 8)
+
+
+def implicit_block(b: int, h: int, w: int, cin: int, kernel: int,
+                   stride: int, c_out: int, block_n: int) -> Tuple[int, int]:
+    """``a_block_geometry`` for an implicit-im2col kernel, with the
+    patch-row target capped by the VMEM budget: each step holds whole
+    SAME-padded frames (channels on the lane axis) plus
+    ``row_vmem_bytes`` per patch row."""
+    ho, wo = conv_out_hw(h, stride), conv_out_hw(w, stride)
+    (plo_h, phi_h), (plo_w, phi_w) = same_pads(h, w, kernel, stride)
+    hp, wp = h + plo_h + phi_h, w + plo_w + phi_w
+    frame = hp * _tiled(wp, 8) * _tiled(cin, 128) * 4
+    row = row_vmem_bytes(kernel * kernel * cin, c_out)
+    per_frame = frame + ho * wo * row
+    if per_frame <= VMEM_BUDGET:
+        cap = (VMEM_BUDGET // per_frame) * ho * wo
+    else:
+        cap = max((VMEM_BUDGET - frame) // row, wo)
+    return a_block_geometry(b, ho, wo, min(block_n, cap))
+
+
 def elem_block(n: int, block_n: int, block_n_elem: int) -> int:
     """Largest kernel-B row block <= block_n_elem that tiles n exactly.
 

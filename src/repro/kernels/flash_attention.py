@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import platform
+
 NEG_INF = -1e30
 
 
@@ -72,7 +74,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention_pallas(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal: bool = True, block_q: int = 128, block_kv: int = 128,
-    interpret: bool = True, scale: Optional[float] = None,
+    interpret: Optional[bool] = None, scale: Optional[float] = None,
 ) -> jax.Array:
     """q, k, v: (B, S, H, D). Returns (B, S, H, D). No GQA here — callers
     expand kv heads (ops.py). ``scale`` overrides D^-0.5 (lane padding)."""
@@ -104,6 +106,6 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=platform.pallas_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)

@@ -14,8 +14,9 @@ benchmark baseline (benchmarks/frontend_bench.py measures the pre-fix
 double-conv path through them) and as test oracles — no production call
 site materializes patches anymore.
 
-Kernels execute in interpret mode on CPU (this container) and compiled mode
-on real TPUs (``interpret=False``).
+Kernels compile on a TPU and run in interpret mode elsewhere
+(``repro.platform.pallas_interpret``); an explicit ``interpret=`` pins the
+mode (tests, compile rehearsals for a described chip).
 """
 from __future__ import annotations
 
@@ -24,7 +25,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro import platform
 from repro.core import mtj as mtj_model
 from repro.core import p2m as p2m_core
 from repro.core import pixel as pixel_model
@@ -152,7 +155,7 @@ def p2m_conv(images: jax.Array, w: jax.Array, theta: jax.Array,
              pixel_params: pixel_model.PixelCircuitParams =
              pixel_model.DEFAULT_PIXEL,
              mtj_params: mtj_model.MTJParams = mtj_model.DEFAULT_MTJ,
-             interpret: bool = True, block_n: int = 256
+             interpret: Optional[bool] = None, block_n: int = 256
              ) -> jax.Array:
     """Legacy fused P2M layer (pre-split). images (B,H,W,C) in [0,1];
     w (k,k,C,Cout) signed quantized weights; theta () threshold. Returns
@@ -188,6 +191,25 @@ def p2m_conv(images: jax.Array, w: jax.Array, theta: jax.Array,
                           pixel_params=pixel_params, mtj_params=mtj_params,
                           block_n=block_n, interpret=interpret)
     return out[:n, :cout].reshape(b, ho, wo, cout)
+
+
+def _on_each_device(step):
+    """``step`` (a frontend step over array operands) as run under the
+    ambient mesh that a sharded engine sets (``jax.set_mesh``).
+
+    The TPU compiler cannot partition a Mosaic kernel, so on a multi-device
+    mesh the step runs inside a ``shard_map`` over every mesh axis with
+    replicated operands: each device computes the frontend of the whole
+    microbatch. The batch-global Hoyer threshold therefore stays ONE
+    reduction over all frames — sharded serving is bit-identical to a
+    single device — and the backbone after it is partitioned as before.
+    The frontend is ~5% of a vgg16 step's MACs, so the redundancy is cheap.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return step
+    return jax.shard_map(step, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
 
 
 def _frontend_shape(images: jax.Array, kernel: int, stride: int, w: jax.Array):
@@ -227,7 +249,8 @@ def _p2m_frontend(images: jax.Array, w: jax.Array, v_th: jax.Array,
             kernel=kernel, stride=stride, pixel_params=pixel_params,
             block_n=block_n, interpret=interpret)
     theta = combine_hoyer_partials(hoyer_partials, v_th.astype(jnp.float32))
-    bb, block_oh = blocking.a_block_geometry(b, ho, wo, block_n)
+    bb, block_oh = blocking.implicit_block(b, h, wd, cin, kernel, stride,
+                                           cout, block_n)
     out, v_partials = p2m_phase_b_pallas(
         u, theta.reshape(1, 1), bits,
         n_valid=n, c_valid=cout,
@@ -246,7 +269,8 @@ def p2m_frontend(images: jax.Array, w: jax.Array, v_th: jax.Array,
                  pixel_params: pixel_model.PixelCircuitParams =
                  pixel_model.DEFAULT_PIXEL,
                  mtj_params: mtj_model.MTJParams = mtj_model.DEFAULT_MTJ,
-                 interpret: bool = True, block_n: Optional[int] = None,
+                 interpret: Optional[bool] = None,
+                 block_n: Optional[int] = None,
                  block_n_elem: Optional[int] = None,
                  precision: Optional[str] = None):
     """Single-pass P2M frontend step: the patch matmul happens exactly once.
@@ -285,11 +309,12 @@ def p2m_frontend(images: jax.Array, w: jax.Array, v_th: jax.Array,
     n, k_eff, cout = _frontend_shape(images, kernel, stride, w)
     bn, bne = autotune.resolve(n, k_eff, cout, block_n, block_n_elem)
     prec = autotune.resolve_precision(n, k_eff, cout, precision)
-    return _p2m_frontend(images, w, v_th, key, chan,
-                         kernel=kernel, stride=stride,
-                         pixel_params=pixel_params, mtj_params=mtj_params,
-                         interpret=interpret, block_n=bn, block_n_elem=bne,
-                         precision=prec)
+    step = functools.partial(
+        _p2m_frontend, kernel=kernel, stride=stride,
+        pixel_params=pixel_params, mtj_params=mtj_params,
+        interpret=platform.pallas_interpret(interpret),
+        block_n=bn, block_n_elem=bne, precision=prec)
+    return _on_each_device(step)(images, w, v_th, key, chan)
 
 
 @functools.partial(jax.jit, static_argnames=("kernel", "stride",
@@ -354,7 +379,7 @@ def p2m_frontend_fused(images: jax.Array, w: jax.Array, v_th: jax.Array,
                        pixel_model.DEFAULT_PIXEL,
                        mtj_params: mtj_model.MTJParams =
                        mtj_model.DEFAULT_MTJ,
-                       interpret: bool = True,
+                       interpret: Optional[bool] = None,
                        block_n: Optional[int] = None,
                        block_n_elem: Optional[int] = None,
                        precision: Optional[str] = None,
@@ -387,13 +412,13 @@ def p2m_frontend_fused(images: jax.Array, w: jax.Array, v_th: jax.Array,
     n, k_eff, cout = _frontend_shape(images, kernel, stride, w)
     bn = autotune.resolve_fused(n, k_eff, cout, block_n)
     prec = autotune.resolve_precision(n, k_eff, cout, precision)
-    return _p2m_frontend_fused(images, w, v_th,
-                               jnp.asarray(theta, jnp.float32), key, chan,
-                               kernel=kernel, stride=stride,
-                               pixel_params=pixel_params,
-                               mtj_params=mtj_params,
-                               interpret=interpret, block_n=bn,
-                               precision=prec, on_device_rng=on_device_rng)
+    step = functools.partial(
+        _p2m_frontend_fused, kernel=kernel, stride=stride,
+        pixel_params=pixel_params, mtj_params=mtj_params,
+        interpret=platform.pallas_interpret(interpret), block_n=bn,
+        precision=prec, on_device_rng=on_device_rng)
+    return _on_each_device(step)(images, w, v_th,
+                                 jnp.asarray(theta, jnp.float32), key, chan)
 
 
 # --- fleet (chip-axis) frontend steps ----------------------------------------
@@ -434,7 +459,7 @@ def p2m_frontend_fleet(images: jax.Array, w: jax.Array, v_th: jax.Array,
                        pixel_params: pixel_model.PixelCircuitParams =
                        pixel_model.DEFAULT_PIXEL,
                        mtj_params: mtj_model.MTJParams = mtj_model.DEFAULT_MTJ,
-                       interpret: bool = True,
+                       interpret: Optional[bool] = None,
                        block_n: Optional[int] = None,
                        block_n_elem: Optional[int] = None,
                        precision: Optional[str] = None):
@@ -458,7 +483,8 @@ def p2m_frontend_fleet(images: jax.Array, w: jax.Array, v_th: jax.Array,
     return _p2m_frontend_fleet(images, w, v_th, keys, chan,
                                kernel=kernel, stride=stride,
                                pixel_params=pixel_params,
-                               mtj_params=mtj_params, interpret=interpret,
+                               mtj_params=mtj_params,
+                               interpret=platform.pallas_interpret(interpret),
                                block_n=bn, block_n_elem=bne, precision=prec)
 
 
@@ -471,7 +497,7 @@ def p2m_frontend_fused_fleet(images: jax.Array, w: jax.Array,
                              pixel_model.DEFAULT_PIXEL,
                              mtj_params: mtj_model.MTJParams =
                              mtj_model.DEFAULT_MTJ,
-                             interpret: bool = True,
+                             interpret: Optional[bool] = None,
                              block_n: Optional[int] = None,
                              precision: Optional[str] = None):
     """Fused single-kernel streaming step over a leading chip axis.
@@ -492,8 +518,9 @@ def p2m_frontend_fused_fleet(images: jax.Array, w: jax.Array,
                                      chan, kernel=kernel, stride=stride,
                                      pixel_params=pixel_params,
                                      mtj_params=mtj_params,
-                                     interpret=interpret, block_n=bn,
-                                     precision=prec)
+                                     interpret=platform.pallas_interpret(
+                                         interpret),
+                                     block_n=bn, precision=prec)
 
 
 @functools.partial(jax.jit, static_argnames=("kernel", "stride",
@@ -525,7 +552,8 @@ def _p2m_frontend_fused_fleet(images: jax.Array, w: jax.Array,
                                              "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_q: int = 128,
-                    block_kv: int = 128, interpret: bool = True) -> jax.Array:
+                    block_kv: int = 128,
+                    interpret: Optional[bool] = None) -> jax.Array:
     """GQA-aware wrapper: (B,S,H,D) x (B,S,Hkv,D) -> (B,S,H,D)."""
     h, hkv = q.shape[2], k.shape[2]
     if hkv != h:

@@ -8,11 +8,11 @@ through the MXU exactly once. The production pipeline:
 
   kernel A (``p2m_phase_a_implicit_pallas``) — implicit im2col + ONE matmul:
       each grid step takes a blocked view of the SAME-padded image and
-      gathers its own (block_n x k*k*C_in) patch rows in VMEM (static
-      strided slices — the zero-padded-to-128-lanes HBM patch matrix of the
-      old path never exists), then performs a single MXU matmul against the
-      (K, 2C) *packed* relu-split weights [w+, w-]. The epilogue splits the
-      two integration phases in-register, applies the per-phase circuit
+      gathers its own (block_n x k*k*C_in) patch rows in VMEM (one strided
+      ref load per tap — the zero-padded-to-128-lanes HBM patch matrix of
+      the old path never exists), then performs a single MXU matmul against
+      the (K, 2C) *packed* relu-split weights [w+, w-]. The epilogue splits
+      the two integration phases in-register, applies the per-phase circuit
       curve, and forms the passive-subtractor difference u, plus block-wise
       partial reductions for the *global* Hoyer threshold (sum |z_clip|,
       sum z_clip^2 with z = u / v_th).
@@ -52,19 +52,23 @@ logit fit all trace in from ``core/pixel.py`` / ``core/mtj.py`` via the
 ``PixelCircuitParams`` / ``MTJParams`` dataclasses (DESIGN.md §3), so the
 fused path can never drift from the reference device model.
 
-Tiling: 1-D grid over patch-row blocks; each kernel-A block is
-(block_n x K) @ (K x C) on the MXU with K, C zero-padded to the 128-lane
-boundary by the caller (ops.py). Zero padding is invisible to the Hoyer
-partials (padded rows/cols give u = g(0) - g(0) = 0 exactly, contributing 0
-to both sums); kernel B's voltage partials mask padding explicitly because
-``conv_voltage(0, theta) != 0``. Partial-reduction outputs are one 128-lane
-row per block (stat lanes selected by iota, TPU-layout friendly).
+Tiling: grids over patch-row blocks, each step capped by a VMEM budget
+(``blocking.implicit_block`` / ``elem_rows_cap``). Zero padding is
+invisible to the Hoyer partials (padded rows/cols give u = g(0) - g(0) = 0
+exactly, contributing 0 to both sums); kernel B's voltage partials mask
+padding explicitly because ``conv_voltage(0, theta) != 0``.
+Partial-reduction outputs are one 128-lane row per block (stat lanes
+selected by iota), stored as (steps, 1, W) arrays so each step's block
+equals the array in its last two dims (``_row_out``).
 
 Randomness enters as one ``mtj.DRAW_BITS_DTYPE`` (uint16) word per output
 (generated by the caller — ``ops.draw_bits``; the draw expression itself is
-single-sourced in ``mtj.bernoulli_from_bits``). On-device
-pltpu.prng_random_bits is a drop-in for real TPU runs — kept out of the
-kernel so interpret=True validation on CPU is bit-exact vs ref.py.
+single-sourced in ``mtj.bernoulli_from_bits``). The fused kernels can
+instead draw on device (``pltpu.prng_random_bits``, compiled kernels only);
+the streamed words keep interpret-mode validation bit-exact vs ref.py.
+
+Execution mode: kernels compile on a TPU and run in interpret mode
+elsewhere (``repro.platform.pallas_interpret``); ``interpret=`` pins it.
 
 ``p2m_conv_pallas`` — the pre-split fused single kernel — is retained as the
 measured baseline for the double-conv fix (benchmarks/frontend_bench.py) and
@@ -73,11 +77,13 @@ for kernel-level regression tests; the frontend no longer calls it.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import platform
 from repro.core import hoyer
 from repro.core import mtj as mtj_model
 from repro.core import p2m as p2m_core
@@ -89,6 +95,10 @@ from repro.kernels import blocking
 from repro.variation.chip import (CHAN_LOGIT_GAIN, CHAN_LOGIT_OFFSET,
                                   CHAN_ROWS, CHAN_U_GAIN, CHAN_U_OFFSET,
                                   identity_operands)
+
+# f32 MXU passes at full precision: the TPU default rounds operands to
+# bfloat16, which would move u far beyond the oracle's ulp-level agreement
+_F32_DOT = jax.lax.Precision.HIGHEST
 
 # lanes of the per-block partial-reduction rows
 STAT_LANES = 128
@@ -111,6 +121,18 @@ def _stat_row(values) -> jax.Array:
     for i, v in enumerate(values):
         row = jnp.where(lane == i, v, row)
     return row
+
+
+def _row_out(steps: int, width: int, index_map):
+    """(spec, shape) of a one-row-per-grid-step output.
+
+    The array is (steps, 1, width) and each step writes its (1, width) row
+    through a squeezed leading block dim: the block's last two dims equal
+    the array's, which is what the TPU lowering requires of a block that is
+    not (8, 128)-aligned. Callers flatten it back to (steps, width).
+    """
+    return (pl.BlockSpec((None, 1, width), index_map),
+            jax.ShapeDtypeStruct((steps, 1, width), jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -139,7 +161,7 @@ def p2m_phase_a_pallas(patches: jax.Array, w: jax.Array, v_th: jax.Array, *,
                        pixel_params: pixel_model.PixelCircuitParams =
                        pixel_model.DEFAULT_PIXEL,
                        block_n: int = 256,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """patches (N, K) f32, w (K, C) f32, v_th (1, 1) f32.
 
     Returns ``(u, hoyer_partials)``: the cached pre-activation (N, C) and the
@@ -150,26 +172,23 @@ def p2m_phase_a_pallas(patches: jax.Array, w: jax.Array, v_th: jax.Array, *,
     n, kk = patches.shape
     c = w.shape[1]
     assert n % block_n == 0, (n, block_n)
-    grid = (n // block_n,)
+    steps = n // block_n
     kernel = functools.partial(_phase_a_kernel, pixel_params=pixel_params)
-    return pl.pallas_call(
+    stats_spec, stats_shape = _row_out(steps, STAT_LANES,
+                                       lambda i: (i, 0, 0))
+    u, stats = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(steps,),
         in_specs=[
             pl.BlockSpec((block_n, kk), lambda i: (i, 0)),
             pl.BlockSpec((kk, c), lambda i: (0, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_n, c), lambda i: (i, 0)),
-            pl.BlockSpec((1, STAT_LANES), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, c), jnp.float32),
-            jax.ShapeDtypeStruct((n // block_n, STAT_LANES), jnp.float32),
-        ],
-        interpret=interpret,
+        out_specs=[pl.BlockSpec((block_n, c), lambda i: (i, 0)), stats_spec],
+        out_shape=[jax.ShapeDtypeStruct((n, c), jnp.float32), stats_shape],
+        interpret=platform.pallas_interpret(interpret),
     )(patches, w, v_th)
+    return u, stats.reshape(steps, STAT_LANES)
 
 
 def combine_hoyer_partials(partials: jax.Array, v_th: jax.Array) -> jax.Array:
@@ -200,30 +219,84 @@ def pack_phase_weights(wm: jax.Array) -> jax.Array:
     return p2m_core.relu_split_pack(wm)
 
 
-def _gather_patches(img_ref, *, kernel: int, stride: int, bb: int,
-                    block_oh: int, wo: int) -> jax.Array:
+class _Implicit(NamedTuple):
+    """Static geometry of one implicit-im2col pallas_call."""
+    imgs: jax.Array       # (B, Hp, Wp, Cin) SAME-padded f32 frames
+    bb: int               # frames per grid step
+    block_oh: int         # output rows per grid step
+    wo: int               # output columns (every step holds whole rows)
+    row_groups: int       # grid axis 1: output-row groups per frame
+    n: int                # patch rows of the whole call
+    bn: int               # patch rows per grid step
+    kk: int               # contraction k*k*Cin
+
+    @property
+    def grid(self):
+        return (self.imgs.shape[0] // self.bb, self.row_groups)
+
+    @property
+    def steps(self) -> int:
+        return self.n // self.bn
+
+    def img_spec(self):
+        _, hp, wp, cin = self.imgs.shape
+        return pl.BlockSpec((self.bb, hp, wp, cin),
+                            lambda bi, i: (bi, 0, 0, 0))
+
+    def rows_spec(self, width: int):
+        """(bn, width) blocks of a (n, width) patch-row-major operand."""
+        return pl.BlockSpec((self.bn, width),
+                            lambda bi, i, rg=self.row_groups: (bi * rg + i, 0))
+
+    def row_out(self, width: int):
+        return _row_out(self.steps, width, lambda bi, i, rg=self.row_groups:
+                        (bi * rg + i, 0, 0))
+
+
+def _implicit(images: jax.Array, kernel: int, stride: int, c_out: int,
+              block_n: int) -> _Implicit:
+    """Pad the frames and fix the grid of an implicit-im2col kernel.
+
+    The patch-row target ``block_n`` is capped by the VMEM budget
+    (``blocking.implicit_block``), so any target — including the tuner's
+    whole-N fused default — yields steps the TPU compiler accepts.
+    """
+    if kernel % 2 == 0:
+        raise ValueError(
+            f"implicit im2col only supports odd kernel sizes (got "
+            f"kernel={kernel}): even kernels cannot reproduce SAME "
+            "convolution placement and would silently mis-pad")
+    b, h, wd, cin = images.shape
+    ho = blocking.conv_out_hw(h, stride)
+    wo = blocking.conv_out_hw(wd, stride)
+    bb, block_oh = blocking.implicit_block(b, h, wd, cin, kernel, stride,
+                                           c_out, block_n)
+    return _Implicit(
+        imgs=blocking.pad_same(images.astype(jnp.float32), kernel, stride),
+        bb=bb, block_oh=block_oh, wo=wo, row_groups=ho // block_oh,
+        n=b * ho * wo, bn=bb * block_oh * wo, kk=kernel * kernel * cin)
+
+
+def _gather_patches(img_ref, *, kernel: int, stride: int, block_oh: int,
+                    wo: int) -> jax.Array:
     """Implicit im2col: the (bb*block_oh*wo, k*k*C_in) patch rows of this
     grid step, gathered from the padded-image block in VMEM.
 
     ``img_ref`` holds (bb, Hp, Wp, C_in); grid axis 1 indexes the output-row
     group, so the window starts at ``program_id(1) * block_oh * stride``.
-    The k*k taps are static strided slices of the window (no gather op, no
-    HBM patch matrix); stacking them on a new axis before the channel axis
-    reproduces ``ops.im2col``'s (tap-major, channel-minor) row layout, so
-    the packed weights reshape straight from the (k, k, C_in, C) tensor.
+    Each of the k*k taps is ONE strided ref load (rows and columns stepping
+    by ``stride`` — no strided value slice, which the TPU compiler refuses,
+    no gather op, no HBM patch matrix); concatenating the taps on the
+    channel axis reproduces ``ops.im2col``'s (tap-major, channel-minor) row
+    layout, so the packed weights reshape straight from the (k, k, C_in, C)
+    tensor.
     """
-    win_h = (block_oh - 1) * stride + kernel
     base = pl.program_id(1) * (block_oh * stride)
-    win = img_ref[:, pl.ds(base, win_h)]          # (bb, win_h, Wp, Cin)
-    cin = win.shape[-1]
-    rows = (block_oh - 1) * stride + 1
-    cols = (wo - 1) * stride + 1
-    taps = [jax.lax.slice(win, (0, di, dj, 0),
-                          (bb, di + rows, dj + cols, cin),
-                          (1, stride, stride, 1))   # (bb, block_oh, wo, Cin)
+    taps = [img_ref[:, pl.ds(base + di, block_oh, stride=stride),
+                    pl.ds(dj, wo, stride=stride), :]  # (bb, block_oh, wo, Cin)
             for di in range(kernel) for dj in range(kernel)]
-    x = jnp.stack(taps, axis=3)                   # (bb, block_oh, wo, kk, Cin)
-    return x.reshape(bb * block_oh * wo, kernel * kernel * cin)
+    x = jnp.concatenate(taps, axis=-1)            # (bb, block_oh, wo, kk*Cin)
+    return x.reshape(-1, x.shape[-1])
 
 
 def _phase_a_epilogue(a: jax.Array, v_th, c_out: int,
@@ -242,11 +315,12 @@ def _phase_a_epilogue(a: jax.Array, v_th, c_out: int,
 
 def _phase_a_implicit_kernel(img_ref, w_ref, v_th_ref, u_ref, stats_ref, *,
                              pixel_params: pixel_model.PixelCircuitParams,
-                             kernel: int, stride: int, bb: int,
+                             kernel: int, stride: int,
                              block_oh: int, wo: int, c_out: int):
-    x = _gather_patches(img_ref, kernel=kernel, stride=stride, bb=bb,
+    x = _gather_patches(img_ref, kernel=kernel, stride=stride,
                         block_oh=block_oh, wo=wo)
-    a = jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
+    a = jnp.dot(x, w_ref[...], precision=_F32_DOT,
+                preferred_element_type=jnp.float32)
     u, stats = _phase_a_epilogue(a, v_th_ref[0, 0], c_out, pixel_params)
     u_ref[...] = u
     stats_ref[...] = stats
@@ -257,7 +331,7 @@ def p2m_phase_a_implicit_pallas(images: jax.Array, w_packed: jax.Array,
                                 pixel_params: pixel_model.PixelCircuitParams =
                                 pixel_model.DEFAULT_PIXEL,
                                 block_n: int = 2048,
-                                interpret: bool = True):
+                                interpret: Optional[bool] = None):
     """Implicit-im2col kernel A: images (B, H, W, Cin) UNPADDED in [0, 1],
     w_packed (k*k*Cin, 2C) from ``pack_phase_weights``, v_th (1, 1) f32.
 
@@ -267,58 +341,39 @@ def p2m_phase_a_implicit_pallas(images: jax.Array, w_packed: jax.Array,
     SAME-padded image itself — the (N, K->128 lanes) patch matrix of the
     old explicit pipeline never exists (DESIGN.md §9).
 
-    ``block_n`` is the target patch-rows per grid step; the actual block is
-    ``bb * block_oh * wo`` with ``block_oh`` a divisor of H' (whole output
-    rows only) and ``bb > 1`` whole frames per step only when a step covers
-    full frames (``blocking.a_block_geometry``) — both restrictions keep
-    the patch-row order identical to ``ops.im2col``.
+    ``block_n`` is the target patch-rows per grid step, capped by the VMEM
+    budget; the actual block is ``bb * block_oh * wo`` with ``block_oh`` a
+    divisor of H' (whole output rows only) and ``bb > 1`` whole frames per
+    step only when a step covers full frames (``blocking.a_block_geometry``)
+    — both restrictions keep the patch-row order identical to
+    ``ops.im2col``.
     """
-    if kernel % 2 == 0:
-        raise ValueError(
-            f"implicit im2col only supports odd kernel sizes (got "
-            f"kernel={kernel}): even kernels cannot reproduce SAME "
-            "convolution placement and would silently mis-pad")
-    b, h, wd, cin = images.shape
-    ho = blocking.conv_out_hw(h, stride)
-    wo = blocking.conv_out_hw(wd, stride)
-    imgs = blocking.pad_same(images.astype(jnp.float32), kernel, stride)
-    hp, wp = imgs.shape[1], imgs.shape[2]
-    bb, block_oh = blocking.a_block_geometry(b, ho, wo, block_n)
-    row_groups = ho // block_oh
-    grid = (b // bb, row_groups)
-    bn = bb * block_oh * wo
-    n = b * ho * wo
-    kk = kernel * kernel * cin
     c2 = w_packed.shape[1]
     c_out = c2 // 2
-    assert w_packed.shape[0] == kk, (w_packed.shape, kk)
+    g = _implicit(images, kernel, stride, c_out, block_n)
+    assert w_packed.shape[0] == g.kk, (w_packed.shape, g.kk)
     kern = functools.partial(
         _phase_a_implicit_kernel, pixel_params=pixel_params, kernel=kernel,
-        stride=stride, bb=bb, block_oh=block_oh, wo=wo, c_out=c_out)
+        stride=stride, block_oh=g.block_oh, wo=g.wo, c_out=c_out)
+    stats_spec, stats_shape = g.row_out(STAT_LANES)
     call = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=g.grid,
         in_specs=[
-            pl.BlockSpec((bb, hp, wp, cin), lambda bi, i: (bi, 0, 0, 0)),
-            pl.BlockSpec((kk, c2), lambda bi, i: (0, 0)),
+            g.img_spec(),
+            pl.BlockSpec((g.kk, c2), lambda bi, i: (0, 0)),
             pl.BlockSpec((1, 1), lambda bi, i: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((bn, c_out),
-                         lambda bi, i, rg=row_groups: (bi * rg + i, 0)),
-            pl.BlockSpec((1, STAT_LANES),
-                         lambda bi, i, rg=row_groups: (bi * rg + i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, c_out), jnp.float32),
-            jax.ShapeDtypeStruct((n // bn, STAT_LANES), jnp.float32),
-        ],
-        interpret=interpret,
+        out_specs=[g.rows_spec(c_out), stats_spec],
+        out_shape=[jax.ShapeDtypeStruct((g.n, c_out), jnp.float32),
+                   stats_shape],
+        interpret=platform.pallas_interpret(interpret),
     )
     # device profiles carry the same stage names as the host spans
     # (repro.obs.trace): kernel A = exposure matmul + Hoyer partials
     with jax.named_scope("p2m_kernel_a"):
-        return call(imgs, w_packed.astype(jnp.float32), v_th)
+        u, stats = call(g.imgs, w_packed.astype(jnp.float32), v_th)
+    return u, stats.reshape(g.steps, STAT_LANES)
 
 
 # ---------------------------------------------------------------------------
@@ -367,6 +422,10 @@ def _device_epilogue(u: jax.Array, theta, bits: jax.Array, chan: jax.Array,
     return draw, v
 
 
+# 0x9E3779B1 (the golden-ratio hash constant) as an int32
+_SEED_MIX = -0x61C8864F
+
+
 def _draw_words(bits_ref, shape, on_device_rng: bool) -> jax.Array:
     """The per-activation uint16 draw words of one grid step.
 
@@ -375,19 +434,20 @@ def _draw_words(bits_ref, shape, on_device_rng: bool) -> jax.Array:
     ``on_device_rng=True`` (real TPUs only — the prng primitives have no
     interpret-mode lowering): ``bits_ref`` is instead a (1, 2) int32 seed
     derived from the caller's key; the per-core generator is seeded per
-    (key, block) — both grid coordinates enter the seed tuple, so every
-    block draws an independent stream and a re-run of the same key replays
-    the same words. This removes the last HBM read of draw words from the
-    fused path (ROADMAP item).
+    (key, block) — the TPU generator takes two seed words, so the linear
+    grid-step index is mixed into the second (golden-ratio multiply), every
+    block draws an independent stream, and a re-run of the same key replays
+    the same words. The words come back as int32 in [0, 2^16), which the
+    shared draw expression widens exactly like streamed uint16 words. This
+    removes the last HBM read of draw words from the fused path.
     """
     if not on_device_rng:
         return bits_ref[...]
     from jax.experimental.pallas import tpu as pltpu
-    pltpu.prng_seed(bits_ref[0, 0], bits_ref[0, 1],
-                    pl.program_id(0), pl.program_id(1))
+    step = pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)
+    pltpu.prng_seed(bits_ref[0, 0], bits_ref[0, 1] ^ (step * _SEED_MIX))
     raw = pltpu.prng_random_bits(shape)
-    return (raw.astype(jnp.uint32)
-            & jnp.uint32(0xFFFF)).astype(mtj_model.DRAW_BITS_DTYPE)
+    return raw.astype(jnp.int32) & 0xFFFF
 
 
 def _phase_b_kernel(u_ref, theta_ref, bits_ref, chan_ref, out_ref, stats_ref,
@@ -421,14 +481,16 @@ def p2m_phase_b_pallas(u: jax.Array, theta: jax.Array, bits: jax.Array, *,
                        pixel_model.DEFAULT_PIXEL,
                        mtj_params: mtj_model.MTJParams = mtj_model.DEFAULT_MTJ,
                        block_n: int = 1024,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """u (N, C) f32 cached pre-activation, theta (1, 1) f32, bits (N, C)
     ``mtj.DRAW_BITS_DTYPE`` (uint16) draw words.
 
     Returns ``(activations, v_conv_partials)``: float {0,1} (N, C) plus the
     per-block masked (sum, min, max) of the subtractor voltage,
     (N / block_n, STAT_LANES). ``n_valid`` / ``c_valid`` are the un-padded
-    extents (static — shapes are known at trace time).
+    extents (static — shapes are known at trace time). A ``block_n`` over
+    the VMEM budget (``blocking.elem_rows_cap``) shrinks to the largest
+    divisor of N within it.
 
     ``chan`` is the per-chip device-variation operand for the epilogue:
     either the (CHAN_ROWS, C) per-channel rows
@@ -447,6 +509,9 @@ def p2m_phase_b_pallas(u: jax.Array, theta: jax.Array, bits: jax.Array, *,
     n, c = u.shape
     if chan is None:
         chan = identity_operands(c)
+    cap = blocking.elem_rows_cap(c)
+    if block_n > cap:
+        block_n = blocking.largest_divisor_at_most(n, cap)
     if chan.ndim == 3:
         n_pix = chan.shape[1]
         assert chan.shape == (CHAN_ROWS, n_pix, c), (chan.shape, c)
@@ -459,32 +524,29 @@ def p2m_phase_b_pallas(u: jax.Array, theta: jax.Array, bits: jax.Array, *,
         assert chan.shape == (CHAN_ROWS, c), (chan.shape, c)
         chan_spec = pl.BlockSpec((CHAN_ROWS, c), lambda i: (0, 0))
     assert n % block_n == 0, (n, block_n)
-    grid = (n // block_n,)
+    steps = n // block_n
     kernel = functools.partial(
         _phase_b_kernel, pixel_params=pixel_params, mtj_params=mtj_params,
         n_valid=n_valid, c_valid=c_valid, block_n=block_n)
+    stats_spec, stats_shape = _row_out(steps, STAT_LANES,
+                                       lambda i: (i, 0, 0))
     call = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(steps,),
         in_specs=[
             pl.BlockSpec((block_n, c), lambda i: (i, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
             pl.BlockSpec((block_n, c), lambda i: (i, 0)),
             chan_spec,
         ],
-        out_specs=[
-            pl.BlockSpec((block_n, c), lambda i: (i, 0)),
-            pl.BlockSpec((1, STAT_LANES), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, c), jnp.float32),
-            jax.ShapeDtypeStruct((n // block_n, STAT_LANES), jnp.float32),
-        ],
-        interpret=interpret,
+        out_specs=[pl.BlockSpec((block_n, c), lambda i: (i, 0)), stats_spec],
+        out_shape=[jax.ShapeDtypeStruct((n, c), jnp.float32), stats_shape],
+        interpret=platform.pallas_interpret(interpret),
     )
     # kernel B = draw + V_CONV voltage-map epilogue
     with jax.named_scope("p2m_kernel_b"):
-        return call(u, theta, bits, chan.astype(jnp.float32))
+        out, stats = call(u, theta, bits, chan.astype(jnp.float32))
+    return out, stats.reshape(steps, STAT_LANES)
 
 
 def combine_v_conv_partials(partials: jax.Array, n_valid: int,
@@ -506,12 +568,13 @@ def _fused_stream_kernel(img_ref, w_ref, v_th_ref, theta_ref, bits_ref,
                          *,
                          pixel_params: pixel_model.PixelCircuitParams,
                          mtj_params: mtj_model.MTJParams,
-                         kernel: int, stride: int, bb: int,
+                         kernel: int, stride: int,
                          block_oh: int, wo: int, c_out: int,
                          on_device_rng: bool = False):
-    x = _gather_patches(img_ref, kernel=kernel, stride=stride, bb=bb,
+    x = _gather_patches(img_ref, kernel=kernel, stride=stride,
                         block_oh=block_oh, wo=wo)
-    a = jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
+    a = jnp.dot(x, w_ref[...], precision=_F32_DOT,
+                preferred_element_type=jnp.float32)
     u, stats = _phase_a_epilogue(a, v_th_ref[0, 0], c_out, pixel_params)
     hoyer_ref[...] = stats                   # FRESH partials: the drift audit
     bits = _draw_words(bits_ref, u.shape, on_device_rng)
@@ -538,7 +601,7 @@ def p2m_fused_stream_pallas(images: jax.Array, w_packed: jax.Array,
                             mtj_model.DEFAULT_MTJ,
                             block_n: int = 2048,
                             rng_seed: jax.Array | None = None,
-                            interpret: bool = True):
+                            interpret: Optional[bool] = None):
     """Steady-state streaming step: the whole sensor frontend in ONE kernel.
 
     images (B, H, W, Cin) unpadded; w_packed (k*k*Cin, 2C); v_th (1, 1);
@@ -569,85 +632,76 @@ def p2m_fused_stream_pallas(images: jax.Array, w_packed: jax.Array,
     epilogue expressions — regression-tested); u and the patch matrix never
     touch HBM at all on this path (DESIGN.md §9).
     """
-    if kernel % 2 == 0:
-        raise ValueError(
-            f"implicit im2col only supports odd kernel sizes (got "
-            f"kernel={kernel})")
-    b, h, wd, cin = images.shape
-    ho = blocking.conv_out_hw(h, stride)
-    wo = blocking.conv_out_hw(wd, stride)
-    imgs = blocking.pad_same(images.astype(jnp.float32), kernel, stride)
-    hp, wp = imgs.shape[1], imgs.shape[2]
-    bb, block_oh = blocking.a_block_geometry(b, ho, wo, block_n)
-    row_groups = ho // block_oh
-    grid = (b // bb, row_groups)
-    bn = bb * block_oh * wo
-    n = b * ho * wo
-    kk = kernel * kernel * cin
     c2 = w_packed.shape[1]
     c_out = c2 // 2
-    assert w_packed.shape[0] == kk, (w_packed.shape, kk)
-    on_device_rng = rng_seed is not None
-    if on_device_rng:
-        if interpret:
-            raise ValueError(
-                "rng_seed (on-device pltpu prng) has no interpret-mode "
-                "lowering; interpret runs must stream ops.draw_bits words")
-        assert rng_seed.shape == (1, 2), rng_seed.shape
-        bits_arg = rng_seed.astype(jnp.int32)
-        bits_spec = pl.BlockSpec((1, 2), lambda bi, i: (0, 0))
-    else:
-        assert bits.shape == (n, c_out), (bits.shape, n, c_out)
-        bits_arg = bits
-        bits_spec = pl.BlockSpec(
-            (bn, c_out), lambda bi, i, rg=row_groups: (bi * rg + i, 0))
-    if chan is None:
-        chan = identity_operands(c_out)
-    if chan.ndim == 3:
-        assert chan.shape == (CHAN_ROWS, ho * wo, c_out), (chan.shape,)
-        # row group i of any frame covers pixel rows [i*block_oh*wo, ...)
-        chan_spec = pl.BlockSpec((CHAN_ROWS, block_oh * wo, c_out),
-                                 lambda bi, i: (0, i, 0))
-    else:
-        assert chan.shape == (CHAN_ROWS, c_out), (chan.shape, c_out)
-        chan_spec = pl.BlockSpec((CHAN_ROWS, c_out), lambda bi, i: (0, 0))
+    g = _implicit(images, kernel, stride, c_out, block_n)
+    assert w_packed.shape[0] == g.kk, (w_packed.shape, g.kk)
+    interpret = platform.pallas_interpret(interpret)
+    bits_arg, bits_spec = _bits_operand(g, bits, rng_seed, c_out, interpret)
+    chan, chan_spec = _chan_operand(g, chan, c_out)
     kern = functools.partial(
         _fused_stream_kernel, pixel_params=pixel_params,
-        mtj_params=mtj_params, kernel=kernel, stride=stride, bb=bb,
-        block_oh=block_oh, wo=wo, c_out=c_out, on_device_rng=on_device_rng)
+        mtj_params=mtj_params, kernel=kernel, stride=stride,
+        block_oh=g.block_oh, wo=g.wo, c_out=c_out,
+        on_device_rng=rng_seed is not None)
+    row_outs = [g.row_out(w) for w in (STAT_LANES, STAT_LANES, c_out)]
     call = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=g.grid,
         in_specs=[
-            pl.BlockSpec((bb, hp, wp, cin), lambda bi, i: (bi, 0, 0, 0)),
-            pl.BlockSpec((kk, c2), lambda bi, i: (0, 0)),
+            g.img_spec(),
+            pl.BlockSpec((g.kk, c2), lambda bi, i: (0, 0)),
             pl.BlockSpec((1, 1), lambda bi, i: (0, 0)),
             pl.BlockSpec((1, 1), lambda bi, i: (0, 0)),
             bits_spec,
             chan_spec,
         ],
-        out_specs=[
-            pl.BlockSpec((bn, c_out),
-                         lambda bi, i, rg=row_groups: (bi * rg + i, 0)),
-            pl.BlockSpec((1, STAT_LANES),
-                         lambda bi, i, rg=row_groups: (bi * rg + i, 0)),
-            pl.BlockSpec((1, STAT_LANES),
-                         lambda bi, i, rg=row_groups: (bi * rg + i, 0)),
-            pl.BlockSpec((1, c_out),
-                         lambda bi, i, rg=row_groups: (bi * rg + i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, c_out), jnp.float32),
-            jax.ShapeDtypeStruct((n // bn, STAT_LANES), jnp.float32),
-            jax.ShapeDtypeStruct((n // bn, STAT_LANES), jnp.float32),
-            jax.ShapeDtypeStruct((n // bn, c_out), jnp.float32),
-        ],
+        out_specs=[g.rows_spec(c_out)] + [spec for spec, _ in row_outs],
+        out_shape=([jax.ShapeDtypeStruct((g.n, c_out), jnp.float32)]
+                   + [shape for _, shape in row_outs]),
         interpret=interpret,
     )
     # fused single-kernel streaming step (DESIGN.md §9)
     with jax.named_scope("p2m_kernel_fused"):
-        return call(imgs, w_packed.astype(jnp.float32), v_th, theta, bits_arg,
-                    chan.astype(jnp.float32))
+        out, hoyer_rows, v_rows, rate_rows = call(
+            g.imgs, w_packed.astype(jnp.float32), v_th, theta, bits_arg,
+            chan)
+    return (out, hoyer_rows.reshape(g.steps, STAT_LANES),
+            v_rows.reshape(g.steps, STAT_LANES),
+            rate_rows.reshape(g.steps, c_out))
+
+
+def _bits_operand(g: _Implicit, bits, rng_seed, c_out: int,
+                  interpret: bool):
+    """(operand, spec) of a fused kernel's draw source: the streamed (N, C)
+    uint16 words, or the (1, 2) int32 seed of the on-device generator."""
+    if rng_seed is None:
+        assert bits.shape == (g.n, c_out), (bits.shape, g.n, c_out)
+        return bits, g.rows_spec(c_out)
+    if interpret:
+        raise ValueError(
+            "rng_seed (on-device pltpu prng) has no interpret-mode "
+            "lowering; interpret runs must stream ops.draw_bits words")
+    assert rng_seed.shape == (1, 2), rng_seed.shape
+    return (rng_seed.astype(jnp.int32),
+            pl.BlockSpec((1, 2), lambda bi, i: (0, 0)))
+
+
+def _chan_operand(g: _Implicit, chan, c_out: int):
+    """(operand, spec) of a fused kernel's variation rows: (CHAN_ROWS, C)
+    per channel, or (CHAN_ROWS, H'*W', C) per spatial pixel — row group i
+    of any frame covers pixel rows [i*block_oh*wo, ...)."""
+    if chan is None:
+        chan = identity_operands(c_out)
+    if chan.ndim == 3:
+        assert chan.shape == (CHAN_ROWS, g.row_groups * g.block_oh * g.wo,
+                              c_out), (chan.shape,)
+        spec = pl.BlockSpec((CHAN_ROWS, g.block_oh * g.wo, c_out),
+                            lambda bi, i: (0, i, 0))
+    else:
+        assert chan.shape == (CHAN_ROWS, c_out), (chan.shape, c_out)
+        spec = pl.BlockSpec((CHAN_ROWS, c_out), lambda bi, i: (0, 0))
+    return chan.astype(jnp.float32), spec
 
 
 # ---------------------------------------------------------------------------
@@ -704,10 +758,10 @@ def _q8_dot(x: jax.Array, wq: jax.Array, dq: jax.Array, acc_dtype):
 def _phase_a_implicit_q8_kernel(img_ref, wq_ref, dq_ref, v_th_ref,
                                 u_ref, stats_ref, *,
                                 pixel_params: pixel_model.PixelCircuitParams,
-                                kernel: int, stride: int, bb: int,
+                                kernel: int, stride: int,
                                 block_oh: int, wo: int, c_out: int,
                                 acc_dtype):
-    x = _gather_patches(img_ref, kernel=kernel, stride=stride, bb=bb,
+    x = _gather_patches(img_ref, kernel=kernel, stride=stride,
                         block_oh=block_oh, wo=wo)
     a = _q8_dot(x, wq_ref[...], dq_ref[...], acc_dtype)
     u, stats = _phase_a_epilogue(a, v_th_ref[0, 0], c_out, pixel_params)
@@ -722,7 +776,7 @@ def p2m_phase_a_implicit_q8_pallas(images: jax.Array, wq_packed: jax.Array,
                                    pixel_model.PixelCircuitParams =
                                    pixel_model.DEFAULT_PIXEL,
                                    block_n: int = 2048,
-                                   interpret: bool = True):
+                                   interpret: Optional[bool] = None):
     """Quantized implicit-im2col kernel A.
 
     Same contract as ``p2m_phase_a_implicit_pallas`` but the packed weight
@@ -733,64 +787,46 @@ def p2m_phase_a_implicit_q8_pallas(images: jax.Array, wq_packed: jax.Array,
     the downstream device chain is literally the same kernel as the f32
     path, which is what makes phase-B parity bit-exact at the operand level.
     """
-    if kernel % 2 == 0:
-        raise ValueError(
-            f"implicit im2col only supports odd kernel sizes (got "
-            f"kernel={kernel})")
-    b, h, wd, cin = images.shape
-    ho = blocking.conv_out_hw(h, stride)
-    wo = blocking.conv_out_hw(wd, stride)
-    imgs = blocking.pad_same(images.astype(jnp.float32), kernel, stride)
-    hp, wp = imgs.shape[1], imgs.shape[2]
-    bb, block_oh = blocking.a_block_geometry(b, ho, wo, block_n)
-    row_groups = ho // block_oh
-    grid = (b // bb, row_groups)
-    bn = bb * block_oh * wo
-    n = b * ho * wo
-    kk = kernel * kernel * cin
     c2 = wq_packed.shape[1]
     c_out = c2 // 2
-    assert wq_packed.shape[0] == kk, (wq_packed.shape, kk)
+    g = _implicit(images, kernel, stride, c_out, block_n)
+    assert wq_packed.shape[0] == g.kk, (wq_packed.shape, g.kk)
     assert wq_packed.dtype == jnp.int8, wq_packed.dtype
     assert dequant_row.shape == (1, c2), (dequant_row.shape, c2)
-    acc_dtype = jnp.float32 if interpret else jnp.int32
+    interpret = platform.pallas_interpret(interpret)
     kern = functools.partial(
         _phase_a_implicit_q8_kernel, pixel_params=pixel_params, kernel=kernel,
-        stride=stride, bb=bb, block_oh=block_oh, wo=wo, c_out=c_out,
-        acc_dtype=acc_dtype)
+        stride=stride, block_oh=g.block_oh, wo=g.wo, c_out=c_out,
+        acc_dtype=jnp.float32 if interpret else jnp.int32)
+    stats_spec, stats_shape = g.row_out(STAT_LANES)
     call = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=g.grid,
         in_specs=[
-            pl.BlockSpec((bb, hp, wp, cin), lambda bi, i: (bi, 0, 0, 0)),
-            pl.BlockSpec((kk, c2), lambda bi, i: (0, 0)),
+            g.img_spec(),
+            pl.BlockSpec((g.kk, c2), lambda bi, i: (0, 0)),
             pl.BlockSpec((1, c2), lambda bi, i: (0, 0)),
             pl.BlockSpec((1, 1), lambda bi, i: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((bn, c_out),
-                         lambda bi, i, rg=row_groups: (bi * rg + i, 0)),
-            pl.BlockSpec((1, STAT_LANES),
-                         lambda bi, i, rg=row_groups: (bi * rg + i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, c_out), jnp.float32),
-            jax.ShapeDtypeStruct((n // bn, STAT_LANES), jnp.float32),
-        ],
+        out_specs=[g.rows_spec(c_out), stats_spec],
+        out_shape=[jax.ShapeDtypeStruct((g.n, c_out), jnp.float32),
+                   stats_shape],
         interpret=interpret,
     )
     with jax.named_scope("p2m_kernel_a_q8"):
-        return call(imgs, wq_packed, dequant_row.astype(jnp.float32), v_th)
+        u, stats = call(g.imgs, wq_packed, dequant_row.astype(jnp.float32),
+                        v_th)
+    return u, stats.reshape(g.steps, STAT_LANES)
 
 
 def _fused_stream_q8_kernel(img_ref, wq_ref, dq_ref, v_th_ref, theta_ref,
                             bits_ref, chan_ref, out_ref, srow_ref, *,
                             pixel_params: pixel_model.PixelCircuitParams,
                             mtj_params: mtj_model.MTJParams,
-                            kernel: int, stride: int, bb: int,
+                            kernel: int, stride: int,
                             block_oh: int, wo: int, c_out: int,
                             acc_dtype, on_device_rng: bool):
-    x = _gather_patches(img_ref, kernel=kernel, stride=stride, bb=bb,
+    x = _gather_patches(img_ref, kernel=kernel, stride=stride,
                         block_oh=block_oh, wo=wo)
     a = _q8_dot(x, wq_ref[...], dq_ref[...], acc_dtype)
     u, _ = _phase_a_epilogue(a, v_th_ref[0, 0], c_out, pixel_params)
@@ -824,7 +860,7 @@ def p2m_fused_stream_q8_pallas(images: jax.Array, wq_packed: jax.Array,
                                mtj_model.DEFAULT_MTJ,
                                block_n: int = 2048,
                                rng_seed: jax.Array | None = None,
-                               interpret: bool = True):
+                               interpret: Optional[bool] = None):
     """Quantized fused streaming step: the int8 frontend in ONE kernel.
 
     Same contract as ``p2m_fused_stream_pallas`` with the quantized weight
@@ -836,83 +872,44 @@ def p2m_fused_stream_q8_pallas(images: jax.Array, wq_packed: jax.Array,
     interpret-mode win (every extra output re-inlines the epilogue chain
     into its consumer) and one store instead of three on TPUs.
     """
-    if kernel % 2 == 0:
-        raise ValueError(
-            f"implicit im2col only supports odd kernel sizes (got "
-            f"kernel={kernel})")
-    b, h, wd, cin = images.shape
-    ho = blocking.conv_out_hw(h, stride)
-    wo = blocking.conv_out_hw(wd, stride)
-    imgs = blocking.pad_same(images.astype(jnp.float32), kernel, stride)
-    hp, wp = imgs.shape[1], imgs.shape[2]
-    bb, block_oh = blocking.a_block_geometry(b, ho, wo, block_n)
-    row_groups = ho // block_oh
-    grid = (b // bb, row_groups)
-    bn = bb * block_oh * wo
-    n = b * ho * wo
-    kk = kernel * kernel * cin
     c2 = wq_packed.shape[1]
     c_out = c2 // 2
-    assert wq_packed.shape[0] == kk, (wq_packed.shape, kk)
+    g = _implicit(images, kernel, stride, c_out, block_n)
+    assert wq_packed.shape[0] == g.kk, (wq_packed.shape, g.kk)
     assert wq_packed.dtype == jnp.int8, wq_packed.dtype
     assert dequant_row.shape == (1, c2), (dequant_row.shape, c2)
-    on_device_rng = rng_seed is not None
-    if on_device_rng:
-        if interpret:
-            raise ValueError(
-                "rng_seed (on-device pltpu prng) has no interpret-mode "
-                "lowering; interpret runs must stream ops.draw_bits words")
-        assert rng_seed.shape == (1, 2), rng_seed.shape
-        bits_arg = rng_seed.astype(jnp.int32)
-        bits_spec = pl.BlockSpec((1, 2), lambda bi, i: (0, 0))
-    else:
-        assert bits.shape == (n, c_out), (bits.shape, n, c_out)
-        bits_arg = bits
-        bits_spec = pl.BlockSpec(
-            (bn, c_out), lambda bi, i, rg=row_groups: (bi * rg + i, 0))
-    if chan is None:
-        chan = identity_operands(c_out)
-    if chan.ndim == 3:
-        assert chan.shape == (CHAN_ROWS, ho * wo, c_out), (chan.shape,)
-        chan_spec = pl.BlockSpec((CHAN_ROWS, block_oh * wo, c_out),
-                                 lambda bi, i: (0, i, 0))
-    else:
-        assert chan.shape == (CHAN_ROWS, c_out), (chan.shape, c_out)
-        chan_spec = pl.BlockSpec((CHAN_ROWS, c_out), lambda bi, i: (0, 0))
-    acc_dtype = jnp.float32 if interpret else jnp.int32
+    interpret = platform.pallas_interpret(interpret)
+    bits_arg, bits_spec = _bits_operand(g, bits, rng_seed, c_out, interpret)
+    chan, chan_spec = _chan_operand(g, chan, c_out)
     width = q8_stat_width(c_out)
     kern = functools.partial(
         _fused_stream_q8_kernel, pixel_params=pixel_params,
-        mtj_params=mtj_params, kernel=kernel, stride=stride, bb=bb,
-        block_oh=block_oh, wo=wo, c_out=c_out, acc_dtype=acc_dtype,
-        on_device_rng=on_device_rng)
+        mtj_params=mtj_params, kernel=kernel, stride=stride,
+        block_oh=g.block_oh, wo=g.wo, c_out=c_out,
+        acc_dtype=jnp.float32 if interpret else jnp.int32,
+        on_device_rng=rng_seed is not None)
+    stats_spec, stats_shape = g.row_out(width)
     call = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=g.grid,
         in_specs=[
-            pl.BlockSpec((bb, hp, wp, cin), lambda bi, i: (bi, 0, 0, 0)),
-            pl.BlockSpec((kk, c2), lambda bi, i: (0, 0)),
+            g.img_spec(),
+            pl.BlockSpec((g.kk, c2), lambda bi, i: (0, 0)),
             pl.BlockSpec((1, c2), lambda bi, i: (0, 0)),
             pl.BlockSpec((1, 1), lambda bi, i: (0, 0)),
             pl.BlockSpec((1, 1), lambda bi, i: (0, 0)),
             bits_spec,
             chan_spec,
         ],
-        out_specs=[
-            pl.BlockSpec((bn, c_out),
-                         lambda bi, i, rg=row_groups: (bi * rg + i, 0)),
-            pl.BlockSpec((1, width),
-                         lambda bi, i, rg=row_groups: (bi * rg + i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, c_out), jnp.float32),
-            jax.ShapeDtypeStruct((n // bn, width), jnp.float32),
-        ],
+        out_specs=[g.rows_spec(c_out), stats_spec],
+        out_shape=[jax.ShapeDtypeStruct((g.n, c_out), jnp.float32),
+                   stats_shape],
         interpret=interpret,
     )
     with jax.named_scope("p2m_kernel_fused_q8"):
-        return call(imgs, wq_packed, dequant_row.astype(jnp.float32), v_th,
-                    theta, bits_arg, chan.astype(jnp.float32))
+        out, srow = call(g.imgs, wq_packed, dequant_row.astype(jnp.float32),
+                         v_th, theta, bits_arg, chan)
+    return out, srow.reshape(g.steps, width)
 
 
 def combine_q8_stream_stats(srow: jax.Array, v_th: jax.Array,
@@ -966,7 +963,7 @@ def p2m_conv_pallas(patches: jax.Array, w: jax.Array, theta: jax.Array,
                     pixel_model.DEFAULT_PIXEL,
                     mtj_params: mtj_model.MTJParams = mtj_model.DEFAULT_MTJ,
                     block_n: int = 256,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """Pre-split fused kernel: patches (N, K) f32, w (K, C) f32,
     theta (1, 1) f32, bits (N, C) uint16 draw words -> float {0,1}
     activations (N, C).
@@ -992,5 +989,5 @@ def p2m_conv_pallas(patches: jax.Array, w: jax.Array, theta: jax.Array,
         ],
         out_specs=pl.BlockSpec((block_n, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, c), jnp.float32),
-        interpret=interpret,
+        interpret=platform.pallas_interpret(interpret),
     )(patches, w, theta, bits)

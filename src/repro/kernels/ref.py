@@ -66,6 +66,25 @@ def p2m_conv_ref_q(patches: jax.Array, w: jax.Array, theta: jax.Array, *,
         p_sw, mtj_params.n_redundant, mtj_params.majority)
 
 
+def draw_mismatches(acts, q_ref, bits) -> tuple:
+    """``(flips, off_boundary)``: how many kernel activations differ from
+    the oracle's draw, and how many of those differences do NOT sit within
+    one uint16 word of the draw threshold.
+
+    acts (N, C) float {0,1} from a kernel pipeline; q_ref (N, C) the
+    oracle's folded activation probability (``p2m_conv_ref_q``); bits the
+    (N, C) draw words both sides consumed. Given the same q the draw is
+    bit-exact, and the implicit-im2col gather makes u differ from the
+    oracle's by ulps at most, so the only legitimate mismatch is a q pushed
+    across a word boundary: flips must be rare and ``off_boundary`` zero.
+    """
+    expected = np.asarray(mtj_model.bernoulli_from_bits(bits, q_ref))
+    mismatch = np.asarray(acts) != expected
+    boundary = np.abs(np.asarray(q_ref, np.float64) * 65536.0
+                      - np.asarray(bits, np.float64)) <= 1.0
+    return int(mismatch.sum()), int((mismatch & ~boundary).sum())
+
+
 # ---------------------------------------------------------------------------
 # single-pass pipeline oracles: kernel A (matmul + Hoyer partials) and
 # kernel B (cached u -> voltage -> draw + masked V_CONV partials)

@@ -47,18 +47,6 @@ RESULTS_DIR = os.path.join(os.path.dirname(__file__),
                            "..", "..", "..", "experiments", "dryrun")
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` across jax versions.
-
-    jax <= 0.4.x returns a list with one dict per device program; newer
-    versions return the dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def optimizer_for(cfg: ArchConfig) -> OptimizerConfig:
     """Big-MoE archs need memory-reduced optimizer state to fit 16 GB/chip."""
     if cfg.num_experts >= 160:
@@ -208,7 +196,7 @@ def corrected_costs(cfg: ArchConfig, shape: ShapeSpec, mesh,
             fn, args = build_cell(c, shape, mesh, rules, opt_cfg)
             lowered = fn.lower(*args)
             compiled = lowered.compile()
-            cost = cost_analysis_dict(compiled)
+            cost = compiled.cost_analysis()
             coll = hlo_analysis.collective_stats(compiled.as_text())
         out[p_n] = {"flops": float(cost.get("flops", 0.0)),
                     "bytes": float(cost.get("bytes accessed", 0.0)),
@@ -264,7 +252,7 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
             compiled = lowered.compile()
             t_compile = clock.now() - t0 - t_lower
             mem = compiled.memory_analysis()
-            cost = cost_analysis_dict(compiled)
+            cost = compiled.cost_analysis()
             coll = hlo_analysis.collective_stats(compiled.as_text())
         # scan-corrected per-device costs (see corrected_costs docstring)
         corr = corrected_costs(cfg, shape, mesh, rules, opt_cfg,

@@ -10,7 +10,7 @@ Hardware constants: TPU v5e — 197 TFLOP/s bf16/chip, 819 GB/s HBM,
 from __future__ import annotations
 
 import re
-from typing import Dict, Optional
+from typing import Dict, Iterator, List, Optional, Tuple
 
 PEAK_FLOPS = 197e12          # bf16 / chip
 HBM_BW = 819e9               # bytes/s / chip
@@ -37,6 +37,56 @@ def _shape_bytes(dtype: str, dims: str) -> int:
     return n * _DTYPE_BYTES.get(dtype, 4)
 
 
+# the opcode: the first lower-case word directly followed by "(" after the
+# result type (layouts such as {1,0:T(8,128)} never put a space before "(")
+_OPCODE_RE = re.compile(r"(?:^|\s)([a-z][a-z0-9\-]*)\(")
+
+
+def _split_operands(args: str) -> List[str]:
+    """The top-level comma-separated operands of ``op(...)...`` text that
+    starts just after the opening parenthesis."""
+    out, depth, cur = [], 1, []
+    for ch in args:
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+            if depth == 0:
+                break
+        if ch == "," and depth == 1:
+            out.append("".join(cur))
+            cur = []
+        else:
+            cur.append(ch)
+    out.append("".join(cur))
+    return [o.strip() for o in out if o.strip()]
+
+
+def _instructions(hlo_text: str) -> Iterator[Tuple[str, List, List, str]]:
+    """``(opcode, result shapes, operand shapes, line)`` per instruction.
+
+    Operand shapes are read inline where the text carries them
+    (``dot(f32[8,16]{1,0} %a, ...)``) and otherwise looked up from the
+    instruction that defined the operand (``dot(%a, %b)``).
+    """
+    defined: Dict[str, List] = {}
+    for line in hlo_text.splitlines():
+        stripped = line.strip()
+        if " = " not in stripped:
+            continue
+        lhs, rhs = stripped.split(" = ", 1)
+        m = _OPCODE_RE.search(rhs)
+        if m is None:
+            continue
+        result = _SHAPE_RE.findall(rhs[:m.start(1)])
+        defined[lhs.split()[-1]] = result
+        operands = []
+        for tok in _split_operands(rhs[m.end():]):
+            inline = _SHAPE_RE.findall(tok)
+            operands.extend(inline[:1] or defined.get(tok.split()[-1], [])[:1])
+        yield m.group(1), result, operands, stripped
+
+
 def collective_stats(hlo_text: str) -> Dict[str, int]:
     """Per-device collective traffic (bytes) by op kind.
 
@@ -46,19 +96,11 @@ def collective_stats(hlo_text: str) -> Dict[str, int]:
     """
     out = {k: 0 for k in _COLLECTIVES}
     out["count"] = 0
-    for line in hlo_text.splitlines():
-        stripped = line.strip()
-        if "=" not in stripped:
+    for op, result, operands, _ in _instructions(hlo_text):
+        base = op[:-len("-start")] if op.endswith("-start") else op
+        if base not in _COLLECTIVES:
             continue
-        base = None
-        for c in _COLLECTIVES:
-            if f" {c}(" in stripped or f" {c}-start(" in stripped:
-                base = c
-                break
-        if base is None:
-            continue
-        sizes = [_shape_bytes(d, dims) for d, dims in
-                 _SHAPE_RE.findall(stripped)]
+        sizes = [_shape_bytes(d, dims) for d, dims in result + operands]
         if not sizes:
             continue
         nbytes = max(sizes)
@@ -86,35 +128,28 @@ def matmul_stats(hlo_text: str) -> Dict[str, float]:
     """
     out = {"dot_count": 0, "dot_flops": 0.0,
            "conv_count": 0, "conv_flops": 0.0}
-    for line in hlo_text.splitlines():
-        stripped = line.strip()
-        if "=" not in stripped:
+    for op, result, operands, line in _instructions(hlo_text):
+        if not result:
             continue
-        shapes = _SHAPE_RE.findall(stripped)
-        if " dot(" in stripped and len(shapes) >= 2:
-            # shapes[0] = output, shapes[1] = lhs
-            out_elems = 1
-            for d in shapes[0][1].split(","):
-                if d:
-                    out_elems *= int(d)
-            lhs_dims = [int(d) for d in shapes[1][1].split(",") if d]
-            m = _CONTRACT_RE.search(stripped)
+        out_elems = 1
+        for d in result[0][1].split(","):
+            if d:
+                out_elems *= int(d)
+        if op == "dot" and operands:
+            lhs_dims = [int(d) for d in operands[0][1].split(",") if d]
+            m = _CONTRACT_RE.search(line)
             contracted = 1
             if m and m.group(1):
                 for i in m.group(1).split(","):
                     contracted *= lhs_dims[int(i)]
             out["dot_count"] += 1
             out["dot_flops"] += 2.0 * out_elems * contracted
-        elif " convolution(" in stripped and len(shapes) >= 3:
-            out_elems = 1
-            for d in shapes[0][1].split(","):
-                if d:
-                    out_elems *= int(d)
+        elif op == "convolution" and len(operands) >= 2:
             # rhs (kernel) shape: contracted extent = all dims but the
             # output-feature one, located via the dim_labels 'o' position
             # (e.g. dim_labels=b01f_01io->b01f); fall back to the last dim
-            rhs_dims = [int(d) for d in shapes[2][1].split(",") if d]
-            m = re.search(r"dim_labels=[^_]+_([^-]+)->", stripped)
+            rhs_dims = [int(d) for d in operands[1][1].split(",") if d]
+            m = re.search(r"dim_labels=[^_]+_([^-]+)->", line)
             o_pos = m.group(1).index("o") if m else len(rhs_dims) - 1
             contracted = 1
             for i, d in enumerate(rhs_dims):

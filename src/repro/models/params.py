@@ -8,6 +8,7 @@ Every module declares a tree of ``ParamSpec`` leaves. From it we derive:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -31,7 +32,7 @@ def is_spec(x) -> bool:
 
 
 def _fan_in(shape: Tuple[int, ...]) -> int:
-    return int(jnp.prod(jnp.asarray(shape[:-1]))) if len(shape) > 1 else shape[0] or 1
+    return math.prod(shape[:-1]) if len(shape) > 1 else shape[0] or 1
 
 
 def init_tree(key: jax.Array, spec_tree, dtype) -> dict:

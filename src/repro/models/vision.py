@@ -26,7 +26,6 @@ class VisionConfig:
     in_hw: int = 32
     p2m: p2m.P2MConfig = p2m.P2MConfig()
     frontend_backend: str = "analog"     # default SensorFrontend backend
-    frontend_interpret: bool = True      # False: compile the Pallas kernel (TPU)
     # None = per-shape autotuner table (kernels/autotune.py); ints pin tiles
     frontend_block_n: Optional[int] = None      # kernel-A patch-row block
     frontend_block_n_elem: Optional[int] = None  # kernel-B row-block cap
@@ -43,7 +42,6 @@ class VisionConfig:
     def frontend(self) -> frontend.FrontendConfig:
         return frontend.FrontendConfig(p2m=self.p2m,
                                        backend=self.frontend_backend,
-                                       interpret=self.frontend_interpret,
                                        block_n=self.frontend_block_n,
                                        block_n_elem=self.frontend_block_n_elem,
                                        variation=self.variation,

@@ -37,6 +37,7 @@ def bench_meta(bench: str, **extra: Any) -> Dict[str, Any]:
         "schema_version": BENCH_SCHEMA_VERSION,
         "jax_version": jax.__version__,
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
         "device_count": jax.device_count(),
         "hostname": socket.gethostname(),
         "platform": platform.platform(),

@@ -463,6 +463,13 @@ class FleetEngine:
 
         return jax.tree.map(one, tree)
 
+    def _on_mesh(self) -> ContextManager:
+        """The engine's mesh as JAX's ambient mesh around a step dispatch:
+        the Pallas frontend reads it to run its kernels once per device
+        (``ops``: the TPU compiler cannot partition a Mosaic kernel)."""
+        return (jax.set_mesh(self.mesh) if self.mesh is not None
+                else contextlib.nullcontext())
+
     def _shard_frames(self, frames: jax.Array) -> jax.Array:
         if self.mesh is None:
             return frames
@@ -570,67 +577,69 @@ class FleetEngine:
         run_fused = bool(fused) and all(c is not None for c in carries)
         total_frames = g * b
 
-        probe = None
-        t0 = clock.now()
-        if run_fused:
-            theta = jnp.asarray(carries, jnp.float32)
-            with self._span("step", chips=g, frames=total_frames,
-                            path="fused"):
-                out = jax.block_until_ready(self._fused_step(
-                    self.params, chips, trims, frames, keys, theta))
-            self.fused_step_count += 1
-            if self._obs is not None:
-                self._obs.counter("serving_fused_steps_total").inc()
-            fresh = np.asarray(out["theta"], np.float64)
-            drifts = np.abs(fresh - np.asarray(carries)) / np.maximum(
-                np.abs(np.asarray(carries)), 1e-9)
-            if float(np.max(drifts)) > self._fused_theta_tol:
-                # some chip's carried threshold went stale: re-serve the
-                # WHOLE step from the exact pipeline (same keys — the rng
-                # sequence is identical either way) and re-seed every carry
-                self._event("drift_guard_fallback",
-                            chip_ids=[it.chip_id for it in group],
-                            drift=float(np.max(drifts)))
+        with self._on_mesh():
+            probe = None
+            t0 = clock.now()
+            if run_fused:
+                theta = jnp.asarray(carries, jnp.float32)
+                with self._span("step", chips=g, frames=total_frames,
+                                path="fused"):
+                    out = jax.block_until_ready(self._fused_step(
+                        self.params, chips, trims, frames, keys, theta))
+                self.fused_step_count += 1
                 if self._obs is not None:
-                    self._obs.counter("serving_fused_fallback_total").inc()
-                out = jax.block_until_ready(self._step(
-                    self.params, chips, trims, frames, keys))
-                self.fused_fallback_count += 1
-                for i, it in enumerate(group):
-                    self._theta_carry[it.chip_id] = float(out["theta"][i])
-                ran_fused = False
-            else:
-                e = self._fused_theta_ema
-                for i, it in enumerate(group):
-                    self._theta_carry[it.chip_id] = (
-                        e * carries[i] + (1.0 - e) * float(fresh[i]))
-                ran_fused = True
-            drift_vals = [float(d) for d in drifts]
-            wall = clock.now() - t0
-            self._record_step(wall, total_frames)
-        else:
-            sync = self._sync_timing or not defer or bool(fused)
-            with self._span("step", chips=g, frames=total_frames,
-                            path="exact"):
-                out = self._step(self.params, chips, trims, frames, keys)
-                if sync:
-                    out = jax.block_until_ready(out)
-            if fused:
-                # the step WANTED fused but some chip had no carry yet (its
-                # stream's first microbatch): the exact run seeds them all —
-                # mirroring VisionEngine's first-microbatch seeding. The
-                # host theta reads synchronize this path regardless of sync.
-                for i, it in enumerate(group):
-                    self._theta_carry[it.chip_id] = float(out["theta"][i])
-            ran_fused = False
-            drift_vals = [0.0] * g
-            wall = clock.now() - t0
-            if sync:
+                    self._obs.counter("serving_fused_steps_total").inc()
+                fresh = np.asarray(out["theta"], np.float64)
+                drifts = np.abs(fresh - np.asarray(carries)) / np.maximum(
+                    np.abs(np.asarray(carries)), 1e-9)
+                if float(np.max(drifts)) > self._fused_theta_tol:
+                    # some chip's carried threshold went stale: re-serve the
+                    # WHOLE step from the exact pipeline (same keys — the rng
+                    # sequence is identical either way) and re-seed every carry
+                    self._event("drift_guard_fallback",
+                                chip_ids=[it.chip_id for it in group],
+                                drift=float(np.max(drifts)))
+                    if self._obs is not None:
+                        self._obs.counter("serving_fused_fallback_total").inc()
+                    out = jax.block_until_ready(self._step(
+                        self.params, chips, trims, frames, keys))
+                    self.fused_fallback_count += 1
+                    for i, it in enumerate(group):
+                        self._theta_carry[it.chip_id] = float(out["theta"][i])
+                    ran_fused = False
+                else:
+                    e = self._fused_theta_ema
+                    for i, it in enumerate(group):
+                        self._theta_carry[it.chip_id] = (
+                            e * carries[i] + (1.0 - e) * float(fresh[i]))
+                    ran_fused = True
+                drift_vals = [float(d) for d in drifts]
+                wall = clock.now() - t0
                 self._record_step(wall, total_frames)
             else:
-                # async: wall below is dispatch-side; the drain patches it
-                probe = clock.WallProbe(out["labels"], t0=t0,
-                                        frames=total_frames, chips=g)
+                sync = self._sync_timing or not defer or bool(fused)
+                with self._span("step", chips=g, frames=total_frames,
+                                path="exact"):
+                    out = self._step(self.params, chips, trims, frames, keys)
+                    if sync:
+                        out = jax.block_until_ready(out)
+                if fused:
+                    # the step WANTED fused but some chip had no carry yet
+                    # (its stream's first microbatch): the exact run seeds
+                    # them all — mirroring VisionEngine's first-microbatch
+                    # seeding. The host theta reads synchronize this path
+                    # regardless of sync.
+                    for i, it in enumerate(group):
+                        self._theta_carry[it.chip_id] = float(out["theta"][i])
+                ran_fused = False
+                drift_vals = [0.0] * g
+                wall = clock.now() - t0
+                if sync:
+                    self._record_step(wall, total_frames)
+                else:
+                    # async: wall below is dispatch-side; the drain patches it
+                    probe = clock.WallProbe(out["labels"], t0=t0,
+                                            frames=total_frames, chips=g)
 
         outs: List[Dict] = []
         for i, it in enumerate(group):

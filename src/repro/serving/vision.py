@@ -323,6 +323,13 @@ class VisionEngine:
         k_eff = pcfg.kernel_size ** 2 * pcfg.in_channels
         return autotune.get(n, k_eff, pcfg.out_channels).fused
 
+    def _on_mesh(self) -> ContextManager:
+        """The engine's mesh as JAX's ambient mesh around a step dispatch:
+        the Pallas frontend reads it to run its kernels once per device
+        (``ops``: the TPU compiler cannot partition a Mosaic kernel)."""
+        return (jax.set_mesh(self.mesh) if self.mesh is not None
+                else contextlib.nullcontext())
+
     def _shard_frames(self, frames: jax.Array) -> jax.Array:
         """Lay the frame batch out over the mesh's batch axes (no-op when
         the engine is unsharded or the batch does not divide the axes)."""
@@ -372,36 +379,38 @@ class VisionEngine:
         # timestamp instead of waiting for the batch-boundary drain
         for p in self._pending.poll():
             self._record_probe(p)
-        probe = None
-        t0 = clock.now()
-        if fused:
-            # the fused drift guard reads the fresh theta on the host, so
-            # this path is inherently synchronized — its wall is honest
-            with self._span("microbatch", frames=n, path="fused"):
-                out, drift, ran_fused = self._fused_classify(params, frames,
-                                                             key)
-            wall = clock.now() - t0
-            if defer and not self._sync_timing:
-                # already measured, but the batch's honest span bounds must
-                # still cover this step
-                self._batch_probes.append(
-                    clock.WallProbe.completed(t0, wall, frames=n))
-        else:
-            drift, ran_fused = 0.0, False
-            if defer and not self._sync_timing:
-                with self._span("microbatch", frames=n, path="exact"):
-                    out = self._step(params, self._shard_frames(frames), key)
-                probe = self._pending.add(
-                    clock.WallProbe(out["labels"], t0=t0, frames=n))
-                self._batch_probes.append(probe)
+        with self._on_mesh():
+            probe = None
+            t0 = clock.now()
+            if fused:
+                # the fused drift guard reads the fresh theta on the host, so
+                # this path is inherently synchronized — its wall is honest
+                with self._span("microbatch", frames=n, path="fused"):
+                    out, drift, ran_fused = self._fused_classify(
+                        params, frames, key)
                 wall = clock.now() - t0
+                if defer and not self._sync_timing:
+                    # already measured, but the batch's honest span bounds
+                    # must still cover this step
+                    self._batch_probes.append(
+                        clock.WallProbe.completed(t0, wall, frames=n))
             else:
-                # honest-but-blocking: device-synchronized wall (classify()
-                # single shots and sync_timing=True streams)
-                with self._span("microbatch", frames=n, path="exact"):
-                    out = jax.block_until_ready(
-                        self._step(params, self._shard_frames(frames), key))
-                wall = clock.now() - t0
+                drift, ran_fused = 0.0, False
+                if defer and not self._sync_timing:
+                    with self._span("microbatch", frames=n, path="exact"):
+                        out = self._step(params, self._shard_frames(frames),
+                                         key)
+                    probe = self._pending.add(
+                        clock.WallProbe(out["labels"], t0=t0, frames=n))
+                    self._batch_probes.append(probe)
+                    wall = clock.now() - t0
+                else:
+                    # honest-but-blocking: device-synchronized wall
+                    # (classify() single shots and sync_timing=True streams)
+                    with self._span("microbatch", frames=n, path="exact"):
+                        out = jax.block_until_ready(self._step(
+                            params, self._shard_frames(frames), key))
+                    wall = clock.now() - t0
         out = dict(out)
         if fused is not None:
             # streaming telemetry: fraction of fused steps and the audited

@@ -92,13 +92,13 @@ class TestTracecheck:
             tracecheck.assert_jit_cache(f, 1)
 
     def test_patch_restores_on_exit(self):
-        import jax._src.pjit as _pjit
-        before = _pjit._create_pjit_jaxpr
+        from jax._src.interpreters import partial_eval as _pe
+        before = _pe.trace_to_jaxpr
         with tracecheck.capture():
             with tracecheck.capture():        # nested: one shared patch
                 pass
-            assert _pjit._create_pjit_jaxpr is not before
-        assert _pjit._create_pjit_jaxpr is before
+            assert _pe.trace_to_jaxpr is not before
+        assert _pe.trace_to_jaxpr is before
 
 
 # --- census: budgets and the injected-regression path -----------------------

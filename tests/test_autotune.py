@@ -83,6 +83,34 @@ class TestPersistence:
             128, 512, 512, False)
 
 
+    def test_refuses_a_table_tuned_on_another_device(self, tmp_path):
+        """Tiles tuned on one device say nothing about another: a table
+        stamped for another backend/device kind must not load."""
+        import json
+        autotune.put(4096, 27, 32, autotune.TileChoice(2048, 4096, 4096,
+                                                       True))
+        path = str(tmp_path / "tiles.json")
+        autotune.save_table(path)
+        with open(path) as f:
+            raw = json.load(f)
+        raw["_meta"].update(backend="tpu", device_kind="TPU v5 lite")
+        with open(path, "w") as f:
+            json.dump(raw, f)
+        autotune.clear()
+        with pytest.raises(ValueError, match="tuned on"):
+            autotune.load_table(path)
+        assert autotune.lookup(4096, 27, 32) is None
+
+    def test_refuses_the_unstamped_cpu_bench_table(self):
+        """The committed BENCH_frontend_tiles.json was tuned on the CPU
+        interpreter before tables carried a device kind."""
+        import os
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with pytest.raises(ValueError, match="unstamped"):
+            autotune.load_table(os.path.join(root,
+                                             "BENCH_frontend_tiles.json"))
+
+
 class TestSearch:
     def test_autotune_frontend_stores_a_candidate(self):
         params = p2m.init_params(jax.random.PRNGKey(0), CFG)
@@ -99,17 +127,21 @@ class TestSearch:
         assert all(ms > 0 for ms in report["two_kernel"].values())
 
     def test_search_result_changes_resolution_not_results(self):
-        """Tuning moves tiles, never numerics: the frontend output for a
-        fixed key is identical before and after the search."""
+        """Tuning moves tiles, never numerics: at the precision the search
+        picks (f32 or int8 — a timing-dependent choice), the frontend
+        output for a fixed key is identical before and after the search."""
         params = p2m.init_params(jax.random.PRNGKey(0), CFG)
         frames = jax.random.uniform(jax.random.PRNGKey(1), (2, 16, 16, 3))
         wq = p2m.quantize_weights(params["w"], CFG.weight_bits)
         key = jax.random.PRNGKey(5)
-        before, aux_b = ops.p2m_frontend(frames, wq, params["v_th"], key)
-        autotune.autotune_frontend(frames, wq, params["v_th"],
-                                   jax.random.PRNGKey(2), repeats=1)
+        before = {prec: ops.p2m_frontend(frames, wq, params["v_th"], key,
+                                         precision=prec)
+                  for prec in ("f32", "int8")}
+        choice, _ = autotune.autotune_frontend(
+            frames, wq, params["v_th"], jax.random.PRNGKey(2), repeats=1)
         after, aux_a = ops.p2m_frontend(frames, wq, params["v_th"], key)
-        np.testing.assert_array_equal(np.asarray(before), np.asarray(after))
+        acts_b, aux_b = before[choice.precision]
+        np.testing.assert_array_equal(np.asarray(acts_b), np.asarray(after))
         np.testing.assert_allclose(float(aux_b["theta"]),
                                    float(aux_a["theta"]), rtol=1e-6)
 

@@ -69,6 +69,24 @@ class TestImplicitIm2col:
             assert bb == 1 or boh == ho     # frames batch only on full rows
             assert bb * boh * wo <= max(bn, wo)
 
+    def test_grid_steps_capped_by_vmem_budget(self):
+        """Any patch-row target — the fused default asks for the whole
+        microbatch — yields steps within the VMEM budget: 64 served
+        frames of 32x32x3 run 4 frames per step."""
+        assert blocking.implicit_block(64, 32, 32, 3, 3, 2, 32,
+                                       64 * 256) == (4, 16)
+        # a small target is left alone
+        assert blocking.implicit_block(64, 32, 32, 3, 3, 2, 32, 512) == (2,
+                                                                         16)
+        cap = blocking.elem_rows_cap(32)
+        assert cap * blocking.row_vmem_bytes(0, 32) <= blocking.VMEM_BUDGET
+        u = jnp.zeros((4 * cap, 32))
+        bits = jnp.zeros((4 * cap, 32), jnp.uint16)
+        _, stats = pk.p2m_phase_b_pallas(u, jnp.ones((1, 1)), bits,
+                                         n_valid=4 * cap, c_valid=32,
+                                         block_n=4 * cap)
+        assert stats.shape[0] >= 4      # the oversized block was split
+
     def test_u_invariant_to_block_rows(self):
         params, frame = _setup(seed=3, b=4, hw=16)
         wq = p2m.quantize_weights(params["w"], CFG.weight_bits)
