@@ -1,0 +1,15 @@
+"""engine.host_syncs_per_microbatch: the waits of the engine's
+host thread on the device inside a stream in the traced window (fused-guard
+waits, blocking exact steps, drains with probes pending, scheduler reads:
+the program's ``host_sync`` marks, one per increment of its
+``serving_host_syncs_total``) over the microbatches it served there (its
+``microbatch`` spans), by ``program_trace``."""
+from bench import program_trace
+
+
+def read(ctx):
+    program = program_trace.of_run(ctx, __file__)
+    if not program or program["syncs"] is None \
+            or program["microbatches"] == 0:
+        return None
+    return program["syncs"] / program["microbatches"]

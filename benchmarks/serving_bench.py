@@ -214,7 +214,7 @@ def _latency_sweep(driver, window_frames: int, capacity_fps: float,
         walls = driver.drive(plan)
         sim = loadgen.simulate(plan, walls, slo_ms=slo_ms)
         obs = obs_mod.Obs(tracing=False)
-        summ = loadgen.record_slo(obs, sim, slo_ms, spans=False)
+        summ = loadgen.record_slo(obs, sim, slo_ms)
         rows.append({"offered_fps": offered, "offered_rel": rel,
                      "n_windows": len(plan),
                      "achieved_fps": sim["achieved_fps"],

@@ -186,7 +186,11 @@ def forward(params: Dict, images: jax.Array, cfg: VisionConfig, *,
     frame's backbone prediction is independent of its batchmates.
     """
     fe = frontend.SensorFrontend(cfg.frontend)
-    x, fe_aux = fe(params["p2m"], images, key=key, mode=backend)
+    # named scopes put each stage's ops under one name in a device trace
+    # (``p2m_frontend``, ``backbone/conv{i}``, ``head``); they are trace-time
+    # metadata and add no operation
+    with jax.named_scope("p2m_frontend"):
+        x, fe_aux = fe(params["p2m"], images, key=key, mode=backend)
     # raw hoyer term; cfg.hoyer_coeff is applied exactly once, at the end
     hoyer_total = fe_aux["hoyer_loss"]
     p2m_sparsity = fe_aux["sparsity"]
@@ -197,42 +201,49 @@ def forward(params: Dict, images: jax.Array, cfg: VisionConfig, *,
                            binary=binary, train=train,
                            bn_momentum=cfg.bn_momentum)
 
-    if cfg.arch.startswith("vgg"):
-        i = 0
-        first_pool = True
-        for item in _VGG_PLANS[cfg.arch]:
-            if item == "M":
-                if first_pool and cfg.remove_first_maxpool:
+    with jax.named_scope("backbone"):
+        if cfg.arch.startswith("vgg"):
+            i = 0
+            first_pool = True
+            for item in _VGG_PLANS[cfg.arch]:
+                if item == "M":
+                    if first_pool and cfg.remove_first_maxpool:
+                        first_pool = False
+                        continue
                     first_pool = False
+                    if x.shape[1] > 1:
+                        # the pool belongs to the conv it follows
+                        with jax.named_scope(f"conv{i - 1}"):
+                            x = _maxpool(x)
                     continue
-                first_pool = False
-                if x.shape[1] > 1:
-                    x = _maxpool(x)
-                continue
-            x, hl, st = conv(params["layers"][f"conv{i}"], x, 1)
-            if train:
-                bn_state[f"conv{i}"] = st
-            hoyer_total += hl
-            i += 1
-    else:
-        names = sorted(params["layers"].keys())
-        for name in names:
-            blk = params["layers"][name]
-            stride = 1
-            h, hl1, st1 = conv(blk["c1"], x, stride)
-            h, hl2, st2 = conv(blk["c2"], h, 1)
-            sc = x
-            blk_state = {"c1": st1, "c2": st2}
-            if "proj" in blk:
-                sc, _, stp = conv(blk["proj"], x, stride, binary=False)
-                blk_state["proj"] = stp
-            if train:
-                bn_state[name] = blk_state
-            x = h + sc
-            hoyer_total += hl1 + hl2
+                with jax.named_scope(f"conv{i}"):
+                    x, hl, st = conv(params["layers"][f"conv{i}"], x, 1)
+                if train:
+                    bn_state[f"conv{i}"] = st
+                hoyer_total += hl
+                i += 1
+        else:
+            names = sorted(params["layers"].keys())
+            for name in names:
+                blk = params["layers"][name]
+                stride = 1
+                with jax.named_scope(name):
+                    h, hl1, st1 = conv(blk["c1"], x, stride)
+                    h, hl2, st2 = conv(blk["c2"], h, 1)
+                    sc = x
+                    blk_state = {"c1": st1, "c2": st2}
+                    if "proj" in blk:
+                        sc, _, stp = conv(blk["proj"], x, stride,
+                                          binary=False)
+                        blk_state["proj"] = stp
+                    x = h + sc
+                if train:
+                    bn_state[name] = blk_state
+                hoyer_total += hl1 + hl2
 
-    x = jnp.mean(x, axis=(1, 2))
-    logits = x @ params["head"]["w"] + params["head"]["b"]
+    with jax.named_scope("head"):
+        x = jnp.mean(x, axis=(1, 2))
+        logits = x @ params["head"]["w"] + params["head"]["b"]
     # surface the full frontend aux (V_CONV stats, global-shutter accounting
     # on hardware backends) minus the loss term consumed above
     aux = {"p2m_sparsity": p2m_sparsity,

@@ -21,6 +21,9 @@ caches, unchanged op census (all three are tested). Submodules:
   histograms (p50/p95/p99 without storing samples).
 * :mod:`repro.obs.trace` — span tracing + structured events in Chrome
   trace format, mirrored to ``jax.profiler.TraceAnnotation``.
+* :mod:`repro.obs.compiles` — ``jax_compiles_total``, the programs JAX
+  loaded (compiled or read from the persistent cache) while the ``Obs``
+  lived.
 * :mod:`repro.obs.export` — JSONL sink, Prometheus-style exposition,
   and the shared ``BENCH_*.json`` meta block.
 """
@@ -29,13 +32,14 @@ from __future__ import annotations
 import contextlib
 from typing import Any, ContextManager, Dict, List, Optional
 
-from repro.obs import clock, export, metrics, trace
+from repro.obs import clock, compiles, export, metrics, trace
 from repro.obs.clock import ProbeSet, WallProbe
 from repro.obs.export import bench_meta
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
 from repro.obs.trace import Tracer
 
-__all__ = ["Obs", "bench_meta", "clock", "export", "metrics", "trace",
+__all__ = ["Obs", "bench_meta", "clock", "compiles", "export",
+           "metrics", "trace",
            "Counter", "Gauge", "Histogram", "MetricsRegistry",
            "ProbeSet", "Tracer", "WallProbe"]
 
@@ -45,12 +49,14 @@ class Obs:
 
     ``tracing=False`` keeps metrics but makes spans/events no-ops;
     ``device_annotations=False`` keeps host spans but skips
-    ``jax.profiler.TraceAnnotation``.
+    ``jax.profiler.TraceAnnotation``. Every ``Obs`` counts JAX's program
+    loads into ``jax_compiles_total`` (:mod:`repro.obs.compiles`).
     """
 
     def __init__(self, tracing: bool = True,
                  device_annotations: bool = True):
         self.registry = MetricsRegistry()
+        compiles.watch(self.registry)
         self.tracer: Optional[Tracer] = (
             Tracer(device_annotations=device_annotations) if tracing
             else None)
@@ -71,14 +77,13 @@ class Obs:
             return contextlib.nullcontext()
         return self.tracer.span(name, **args)
 
+    def mark(self, name: str) -> None:
+        if self.tracer is not None:
+            self.tracer.mark(name)
+
     def event(self, name: str, **args: Any) -> None:
         if self.tracer is not None:
             self.tracer.event(name, **args)
-
-    def complete_span(self, name: str, t0: float, t1: float,
-                      **args: Any) -> None:
-        if self.tracer is not None:
-            self.tracer.complete(name, t0, t1, **args)
 
     # -- export -------------------------------------------------------------
     def records(self, meta: Optional[Dict[str, Any]] = None

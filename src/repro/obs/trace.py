@@ -4,11 +4,11 @@ A :class:`Tracer` records two record kinds:
 
 * **Spans** — ``with tracer.span("microbatch", frames=8):`` blocks with a
   start timestamp and duration. Nesting is tracked host-side (a span
-  stack), and each span also enters ``jax.profiler.TraceAnnotation`` so a
-  device profile (``jax.profiler.trace``) carries the *same* names as the
-  host trace — one vocabulary for both. Spans measured elsewhere (the
-  async :class:`~repro.obs.clock.WallProbe` latencies) are attached with
-  :meth:`complete`.
+  stack), and each span also enters ``jax.profiler.TraceAnnotation`` (its
+  args as the annotation's stats) so a device profile
+  (``jax.profiler.trace``) carries the *same* names as the host trace, on
+  the profiler's clock — one vocabulary for both. Device intervals come
+  from that profile, never from host-side guesses.
 * **Events** — instantaneous structured facts (``recalibration``,
   ``drift_guard_fallback``, ``fleet_join`` ...) with chip_id attribution
   in their args.
@@ -59,7 +59,7 @@ class Tracer:
     def span(self, name: str, **args: Any) -> Iterator[None]:
         t0 = clock.now()
         self._stack.append(name)
-        ann = (_TraceAnnotation(name) if self._device_annotations
+        ann = (_TraceAnnotation(name, **args) if self._device_annotations
                else contextlib.nullcontext())
         try:
             with ann:
@@ -74,14 +74,12 @@ class Tracer:
                 "args": args,
             })
 
-    def complete(self, name: str, t0: float, t1: float,
-                 tid: str = "device", **args: Any) -> None:
-        """Attach an externally-timed span (e.g. an async probe latency)."""
-        self.records.append({
-            "ph": "X", "name": name, "cat": "span",
-            "ts": self._us(t0), "dur": (t1 - t0) * 1e6,
-            "pid": 0, "tid": tid, "depth": 0, "args": args,
-        })
+    def mark(self, name: str) -> None:
+        """A zero-length annotation on the profiler's clock, and nothing in
+        ``records``: a device trace counts the marks inside its window."""
+        if self._device_annotations:
+            with _TraceAnnotation(name):
+                pass
 
     # -- events -------------------------------------------------------------
     def event(self, name: str, **args: Any) -> None:

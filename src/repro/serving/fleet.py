@@ -69,7 +69,7 @@ from repro import sharding
 from repro.core import energy, hoyer, p2m
 from repro.models import vision
 from repro.obs import clock
-from repro.serving.vision import _merge_outputs
+from repro.serving.vision import HOST_SYNC_MARK, HOST_SYNCS, _merge_outputs
 from repro.variation import chip as chip_mod
 from repro.variation.calibrate import solve_trim, target_rates
 
@@ -398,6 +398,11 @@ class FleetEngine:
         if self._obs is not None:
             self._obs.event(name, **args)
 
+    def _host_sync(self) -> None:
+        if self._obs is not None:
+            self._obs.counter(HOST_SYNCS).inc()
+            self._obs.mark(HOST_SYNC_MARK)
+
     def _record_step(self, wall_s: float, n_frames: int) -> None:
         if self._obs is not None:
             self._obs.histogram("fleet_step_wall_ms").record(wall_s * 1e3)
@@ -586,6 +591,7 @@ class FleetEngine:
                                 path="fused"):
                     out = jax.block_until_ready(self._fused_step(
                         self.params, chips, trims, frames, keys, theta))
+                self._host_sync()
                 self.fused_step_count += 1
                 if self._obs is not None:
                     self._obs.counter("serving_fused_steps_total").inc()
@@ -603,6 +609,7 @@ class FleetEngine:
                         self._obs.counter("serving_fused_fallback_total").inc()
                     out = jax.block_until_ready(self._step(
                         self.params, chips, trims, frames, keys))
+                    self._host_sync()
                     self.fused_fallback_count += 1
                     for i, it in enumerate(group):
                         self._theta_carry[it.chip_id] = float(out["theta"][i])
@@ -623,6 +630,8 @@ class FleetEngine:
                     out = self._step(self.params, chips, trims, frames, keys)
                     if sync:
                         out = jax.block_until_ready(out)
+                if sync and stream:
+                    self._host_sync()
                 if fused:
                     # the step WANTED fused but some chip had no carry yet
                     # (its stream's first microbatch): the exact run seeds
@@ -735,14 +744,13 @@ class FleetEngine:
             outstanding = (sum(1 for _, _, p in steps if p is not None)
                            if self._obs is not None else 0)
             drain_t0 = clock.now() if self._obs is not None else 0.0
+            if outstanding:
+                self._host_sync()
             for group, outs, probe in steps:
                 if probe is None:
                     continue
                 wall = probe.wait()
                 self._record_step(wall, probe.tags["frames"])
-                if self._obs is not None:
-                    self._obs.complete_span("step_ready", probe.t0,
-                                            probe.t0 + wall, **probe.tags)
                 total = probe.tags["frames"]
                 for it, o in zip(group, outs):
                     share = it.frames.shape[0] / total

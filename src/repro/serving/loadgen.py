@@ -35,10 +35,8 @@ their own clock. This module supplies that clock without importing one:
   decomposition in the PR 8 instruments: log-bucket histograms
   (``serving_request_latency_ms`` / ``serving_queue_wait_ms`` /
   ``serving_ttfa_ms`` — p50/p95/p99 read back from the buckets), the
-  ``slo_violations_total`` burn counter, the ``serving_queue_depth``
-  high-water gauge, and per-request ``request``/``queue_wait`` complete
-  spans (virtual times re-anchored onto the caller's clock origin, so
-  the module itself never reads a clock).
+  ``slo_violations_total`` burn counter and the ``serving_queue_depth``
+  high-water gauge.
 
 Nothing here imports jax or ``repro.obs.clock`` — the generator is pure
 host arithmetic, so determinism tests can ban the clock outright.
@@ -272,17 +270,14 @@ def simulate(batches: Sequence[Microbatch], service_s: ServiceTimes,
             "achieved_fps": frames / done if done > 0 else 0.0}
 
 
-def record_slo(obs, sim: Dict, slo_ms: float,
-               anchor: float = 0.0, spans: bool = True) -> Dict:
+def record_slo(obs, sim: Dict, slo_ms: float) -> Dict:
     """Land one simulation's SLO accounting in a ``repro.obs.Obs``.
 
     Histograms carry the latency decomposition (quantiles are read back
     from the log buckets — no sample retention); ``slo_violations_total``
     burns one count per request over ``slo_ms``; the queue-depth gauge
-    latches the high-water mark. ``anchor`` re-bases the virtual
-    timestamps for the per-request complete spans (callers pass their
-    clock origin; this module never reads a clock). Returns the
-    quantile summary used by the bench curves.
+    latches the high-water mark. Returns the quantile summary used by the
+    bench curves.
     """
     lat = obs.histogram("serving_request_latency_ms")
     qw = obs.histogram("serving_queue_wait_ms")
@@ -294,15 +289,6 @@ def record_slo(obs, sim: Dict, slo_ms: float,
         qw.record(row["queue_wait_ms"])
         if row["latency_ms"] > slo_ms:
             violations.inc()
-        if spans:
-            t_arr = anchor + row["t_arrival_ms"] / 1e3
-            t_disp = t_arr + row["queue_wait_ms"] / 1e3
-            t_ready = t_disp + row["service_ms"] / 1e3
-            obs.complete_span("queue_wait", t_arr, t_disp,
-                              req=row["req_id"], batch=row["batch"])
-            obs.complete_span("request", t_arr, t_ready,
-                              req=row["req_id"], batch=row["batch"],
-                              chip=row["chip_id"])
     for row in sim["batches"]:
         ttfa.record(row["ttfa_ms"])
     obs.gauge("serving_queue_depth").set(sim["queue_depth_high_water"])

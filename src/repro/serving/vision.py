@@ -42,10 +42,13 @@ between microbatches. ``sync_timing=True`` restores the old
 block-per-microbatch behavior bit-exactly (benches that want per-step
 device-synchronized walls). Pass ``obs=`` (a ``repro.obs.Obs``) and the
 engine additionally records latency histograms (p50/p95/p99), frame
-counters, spans (``stream``/``microbatch``/``kernel_dispatch``) and
-structured events (recalibration, drift-guard fallback) — with ``obs=None``
-(the default) every instrument call is behind one ``is None`` check:
-outputs are bit-identical and jit caches/census provably unchanged.
+counters, the host's waits on the device (``serving_host_syncs_total``),
+spans (``stream`` > ``key_fold`` / ``microbatch`` > ``theta_sync``, then
+``drain`` and ``merge``; ``stream`` carries ``item=``, ``microbatch``
+``item=`` and ``part=``) and structured events (recalibration, drift-guard
+fallback) — with ``obs=None`` (the default) every instrument call is
+behind one ``is None`` check: outputs are bit-identical and jit
+caches/census provably unchanged.
 
 Per-chip realism: when ``cfg.variation`` names a sampled chip, pass the
 chip's ``calibration=`` artifact (variation/calibrate.py) and the engine
@@ -84,6 +87,18 @@ from repro.variation import chip as chip_mod
 
 # logical axes of a (B, H, W, C) frame batch: shard batch, replicate pixels
 FRAME_AXES = ("batch", None, None, None)
+# one count per wait of the engine's host thread on the device in a stream
+HOST_SYNCS = "serving_host_syncs_total"
+# each increment of HOST_SYNCS also marks the profiler's clock, so a device
+# trace counts the syncs inside its window
+HOST_SYNC_MARK = "host_sync"
+
+
+def _named(fn: functools.partial, name: str) -> functools.partial:
+    """``fn`` under ``name``: jit names the step's program after it
+    (``jit_<name>``), so a device trace can tell the two steps apart."""
+    fn.__name__ = name
+    return fn
 
 
 class VisionEngine:
@@ -148,10 +163,11 @@ class VisionEngine:
             # model + frontend params are small — replicate once, serve many
             params = jax.device_put(params, NamedSharding(mesh, P()))
         self.params = params
-        self._step = jax.jit(functools.partial(self._forward, cfg=cfg,
-                                               backend=self.backend))
-        self._fused_step = jax.jit(functools.partial(
-            self._forward_fused, cfg=cfg, backend=self.backend))
+        self._step = jax.jit(_named(functools.partial(
+            self._forward, cfg=cfg, backend=self.backend), "_forward"))
+        self._fused_step = jax.jit(_named(functools.partial(
+            self._forward_fused, cfg=cfg, backend=self.backend),
+            "_forward_fused"))
         # modeled sensor-side frame budget at this engine's geometry
         # (core/energy §3.4) — constant telemetry, computed once
         lat = energy.frame_latency_us(self._frame_spec())
@@ -189,9 +205,11 @@ class VisionEngine:
 
     def _record_probe(self, p: clock.WallProbe) -> None:
         self._record_latency(p.latency, p.tags.get("frames", 0))
+
+    def _host_sync(self) -> None:
         if self._obs is not None:
-            self._obs.complete_span("microbatch_ready", p.t0,
-                                    p.t0 + p.latency, **p.tags)
+            self._obs.counter(HOST_SYNCS).inc()
+            self._obs.mark(HOST_SYNC_MARK)
 
     def _finish_batch(self, outs: List[Dict], sizes: List[int]) -> Dict:
         """Merge one incoming batch's microbatch outputs; in async mode
@@ -200,10 +218,14 @@ class VisionEngine:
         interval. Sync mode with a single microbatch returns the output
         untouched — bit-identical to the pre-obs engine."""
         probes, self._batch_probes = self._batch_probes, []
-        for p in self._pending.drain():
-            self._record_probe(p)
-        merged = (_merge_outputs(outs, sizes) if len(outs) > 1
-                  else outs[0])
+        if len(self._pending):
+            self._host_sync()
+        with self._span("drain"):
+            for p in self._pending.drain():
+                self._record_probe(p)
+        with self._span("merge"):
+            merged = (_merge_outputs(outs, sizes) if len(outs) > 1
+                      else outs[0])
         if probes:
             t0, t1 = clock.span_bounds(probes)
             wall = max(t1 - t0, 1e-9)
@@ -353,7 +375,8 @@ class VisionEngine:
 
     def _classify(self, frames: jax.Array, key: Optional[jax.Array],
                   advance: bool, fused: Optional[bool] = None,
-                  defer: bool = False) -> Dict:
+                  defer: bool = False, item: Optional[int] = None,
+                  part: int = 0) -> Dict:
         """``fused`` is tri-state: None = not a pallas-stream call (classify
         and non-pallas streams — no streaming telemetry keys, bit-identical
         to a plain engine); False = a pallas stream step the tuner/caller
@@ -368,9 +391,12 @@ class VisionEngine:
         by a :class:`repro.obs.clock.WallProbe` at the next non-blocking
         poll or the batch-boundary drain, and ``_finish_batch`` patches
         the merged ``wall_ms``. The per-microbatch ``wall_ms`` on this
-        path is the dispatch-side elapsed time only."""
+        path is the dispatch-side elapsed time only. ``item`` / ``part``
+        (a stream's item index and the microbatch's place in it) label the
+        ``microbatch`` span."""
         if key is None:
-            key = jax.random.fold_in(self._key, self._frame_count)
+            with self._span("key_fold"):
+                key = jax.random.fold_in(self._key, self._frame_count)
             self._frame_count += 1
         params = self.params if self.lifetime is None else self._aged_params()
         n = frames.shape[0]
@@ -385,7 +411,8 @@ class VisionEngine:
             if fused:
                 # the fused drift guard reads the fresh theta on the host, so
                 # this path is inherently synchronized — its wall is honest
-                with self._span("microbatch", frames=n, path="fused"):
+                with self._span("microbatch", frames=n, path="fused",
+                                item=item, part=part):
                     out, drift, ran_fused = self._fused_classify(
                         params, frames, key)
                 wall = clock.now() - t0
@@ -397,7 +424,8 @@ class VisionEngine:
             else:
                 drift, ran_fused = 0.0, False
                 if defer and not self._sync_timing:
-                    with self._span("microbatch", frames=n, path="exact"):
+                    with self._span("microbatch", frames=n, path="exact",
+                                    item=item, part=part):
                         out = self._step(params, self._shard_frames(frames),
                                          key)
                     probe = self._pending.add(
@@ -407,9 +435,12 @@ class VisionEngine:
                 else:
                     # honest-but-blocking: device-synchronized wall
                     # (classify() single shots and sync_timing=True streams)
-                    with self._span("microbatch", frames=n, path="exact"):
+                    with self._span("microbatch", frames=n, path="exact",
+                                    item=item, part=part):
                         out = jax.block_until_ready(self._step(
                             params, self._shard_frames(frames), key))
+                    if defer:
+                        self._host_sync()
                     wall = clock.now() - t0
         out = dict(out)
         if fused is not None:
@@ -428,6 +459,8 @@ class VisionEngine:
             # when their probe latches (poll or drain)
             self._record_latency(wall, n)
         if self.lifetime is not None and advance:
+            if defer and self._scheduler is not None:
+                self._host_sync()       # observe reads the channel rates
             out.update(self._advance_lifetime(out, n))
         return out
 
@@ -444,25 +477,32 @@ class VisionEngine:
         exact path (same key — the rng sequence is identical either way,
         so guard firings are key-free and deterministic in the frames) and
         the carry is re-seeded. Otherwise the carry advances as
-        ``ema * carry + (1 - ema) * fresh``.
+        ``ema * carry + (1 - ema) * fresh``. Each step is dispatched, then
+        waited for with its threshold read under ``theta_sync``.
         """
         frames = self._shard_frames(frames)
         if self._theta_carry is None:
-            out = dict(jax.block_until_ready(
-                self._step(params, frames, key)))
-            # the exact path thresholds at its own fresh theta; mirroring it
-            # under the fused path's aux key keeps every microbatch output
-            # of a stream structurally identical for _merge_outputs
-            out["theta_used"] = out["theta"]
-            self._theta_carry = float(out["theta"])
+            out = self._step(params, frames, key)
+            with self._span("theta_sync"):
+                out = dict(jax.block_until_ready(out))
+                # the exact path thresholds at its own fresh theta;
+                # mirroring it under the fused path's aux key keeps every
+                # microbatch output of a stream structurally identical for
+                # _merge_outputs
+                out["theta_used"] = out["theta"]
+                self._theta_carry = float(out["theta"])
+            self._host_sync()
             return out, 0.0, False
         carry = self._theta_carry
-        out = jax.block_until_ready(self._fused_step(
-            params, frames, key, jnp.asarray(carry, jnp.float32)))
-        self.fused_step_count += 1
-        if self._obs is not None:
-            self._obs.counter("serving_fused_steps_total").inc()
-        fresh = float(out["theta"])
+        out = self._fused_step(params, frames, key,
+                               jnp.asarray(carry, jnp.float32))
+        with self._span("theta_sync"):
+            out = jax.block_until_ready(out)
+            self.fused_step_count += 1
+            if self._obs is not None:
+                self._obs.counter("serving_fused_steps_total").inc()
+            fresh = float(out["theta"])
+        self._host_sync()
         drift = abs(fresh - carry) / max(abs(carry), 1e-9)
         if drift > self._fused_theta_tol:
             # the carried threshold went stale (scene change): serve this
@@ -471,10 +511,12 @@ class VisionEngine:
                         theta_carry=carry, theta_fresh=fresh)
             if self._obs is not None:
                 self._obs.counter("serving_fused_fallback_total").inc()
-            out = dict(jax.block_until_ready(
-                self._step(params, frames, key)))
-            out["theta_used"] = out["theta"]
-            self._theta_carry = float(out["theta"])
+            out = self._step(params, frames, key)
+            with self._span("theta_sync"):
+                out = dict(jax.block_until_ready(out))
+                out["theta_used"] = out["theta"]
+                self._theta_carry = float(out["theta"])
+            self._host_sync()
             self.fused_fallback_count += 1
             return out, drift, False
         self._theta_carry = (self._fused_theta_ema * carry
@@ -506,7 +548,7 @@ class VisionEngine:
         # it (a stale carry from a previous stream could sit inside the
         # tolerance yet describe a different scene)
         self._theta_carry = None
-        for frames in frame_batches:
+        for item, frames in enumerate(frame_batches):
             mb = self.microbatch
             b, h, w = frames.shape[0], frames.shape[1], frames.shape[2]
 
@@ -517,21 +559,27 @@ class VisionEngine:
                     return None
                 return self._stream_fused_enabled(n_frames, h, w)
 
-            with self._span("stream", frames=b):
+            with self._span("stream", frames=b, item=item):
                 if not mb or b <= mb:
                     outs = [self._classify(frames, None, advance=True,
-                                           fused=fused_arg(b), defer=True)]
+                                           fused=fused_arg(b), defer=True,
+                                           item=item)]
                     sizes = [b]
                 else:
-                    base = jax.random.fold_in(self._key, self._frame_count)
+                    with self._span("key_fold"):
+                        base = jax.random.fold_in(self._key,
+                                                  self._frame_count)
                     self._frame_count += 1
                     starts = list(range(0, b, mb))
                     sizes = [min(mb, b - i) for i in starts]
-                    outs = [self._classify(frames[i:i + sz],
-                                           key=jax.random.fold_in(base, j),
-                                           advance=True, fused=fused_arg(sz),
-                                           defer=True)
-                            for j, (i, sz) in enumerate(zip(starts, sizes))]
+                    outs = []
+                    for j, (i, sz) in enumerate(zip(starts, sizes)):
+                        chunk = frames[i:i + sz]
+                        with self._span("key_fold"):
+                            key = jax.random.fold_in(base, j)
+                        outs.append(self._classify(
+                            chunk, key=key, advance=True, fused=fused_arg(sz),
+                            defer=True, item=item, part=j))
                 merged = self._finish_batch(outs, sizes)
             yield merged
 

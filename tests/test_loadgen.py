@@ -247,7 +247,7 @@ class TestRecordSloAndKnee:
                                          4e-3)
         sim = loadgen.simulate(plan, _model, slo_ms=3.0)
         obs = obs_mod.Obs()
-        summ = loadgen.record_slo(obs, sim, 3.0, anchor=100.0)
+        summ = loadgen.record_slo(obs, sim, 3.0)
         reg = obs.registry
         assert reg.histogram("serving_request_latency_ms").count == 40
         assert reg.histogram("serving_queue_wait_ms").count == 40
@@ -259,21 +259,9 @@ class TestRecordSloAndKnee:
         assert reg.gauge("serving_queue_depth").value == \
             sim["queue_depth_high_water"]
         assert summ["latency_p50_ms"] <= summ["latency_p99_ms"]
-        # spans re-anchored onto the caller's origin, one pair/request,
-        # with durations exactly matching the simulated decomposition
-        reqs = obs.tracer.spans("request")
-        waits = obs.tracer.spans("queue_wait")
-        assert len(reqs) == 40 == len(waits)
-        by_id = {s["args"]["req"]: s for s in reqs}
-        for row in sim["requests"]:
-            assert by_id[row["req_id"]]["dur"] == pytest.approx(
-                row["latency_ms"] * 1e3, rel=1e-6, abs=1e-3)
-        # arrivals keep their virtual spacing after re-anchoring
-        t0 = min(s["ts"] for s in reqs)
-        spread = max(s["ts"] for s in reqs) - t0
-        arr = [r["t_arrival_ms"] for r in sim["requests"]]
-        assert spread == pytest.approx((max(arr) - min(arr)) * 1e3,
-                                       rel=1e-6, abs=1e-3)
+        # the simulation's virtual times land in histograms only: no span
+        # claims an interval on the host's clock
+        assert obs.tracer.spans() == []
 
     def test_find_knee_latency_and_slowdown_criteria(self):
         def row(fps, p99, slowdown=1.0):

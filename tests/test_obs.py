@@ -160,15 +160,6 @@ class TestTracer:
         assert mb1["ts"] <= mb2["ts"]
         assert stream["args"] == {"frames": 8}
 
-    def test_complete_span_and_queries(self):
-        tr = Tracer(device_annotations=False)
-        t0 = clock.now()
-        tr.complete("microbatch_ready", t0, t0 + 0.5, frames=8)
-        (s,) = tr.spans("microbatch_ready")
-        assert s["dur"] == pytest.approx(0.5e6, rel=1e-6)
-        assert s["tid"] == "device"
-        assert tr.events() == []
-
 
 # ---------------------------------------------------------------------------
 # clock probes
@@ -315,7 +306,7 @@ class TestEngineObs:
         names = [r["name"] for r in obs.tracer.records]
         assert names.count("stream") == 2
         assert names.count("microbatch") == 2
-        assert "microbatch_ready" in names
+        assert names.count("drain") == 2 and names.count("merge") == 2
 
     def test_constant_keys_survive_mixed_microbatch_merge(self, params):
         """6 frames at microbatch=4 -> microbatches of 4 and 2; the modeled
@@ -349,6 +340,121 @@ class TestEngineObs:
         # the refresh itself ran under a tester-solve span
         assert obs.tracer.spans("recal_solve")
         assert obs.registry.gauge("lifetime_rate_err").value is not None
+
+
+def _within(inner, outer):
+    return (inner["ts"] >= outer["ts"] - 1e-3 and inner["ts"] + inner["dur"]
+            <= outer["ts"] + outer["dur"] + 1e-3)
+
+
+class TestStageTracing:
+    def test_model_stages_are_scoped(self):
+        """Every stage of the served model carries its scope into the
+        lowered step's op locations, which a device trace reads."""
+        cfg = vision.VisionConfig(name="t16", arch="vgg16", num_classes=10)
+        p = vision.init_params(jax.random.PRNGKey(0), cfg)
+        eng = VisionEngine(cfg, p, backend="pallas", seed=0)
+        frames = _batches([2])[0]
+        text = eng._step.lower(p, frames, jax.random.PRNGKey(1)).as_text(
+            debug_info=True)
+        for scope in (["p2m_frontend", "head"]
+                      + [f"backbone/conv{i}/" for i in range(13)]):
+            assert scope in text, scope
+        assert "backbone/conv13" not in text
+
+    def test_stream_spans_nest_per_item(self, params):
+        obs = obs_mod.Obs()
+        eng = VisionEngine(CFG, params, backend="pallas", seed=0, obs=obs,
+                           microbatch=4)
+        list(eng.stream(_batches([8, 8, 8])))
+        tr = obs.tracer
+        streams = tr.spans("stream")
+        assert [s["args"]["item"] for s in streams] == [0, 1, 2]
+        for s in streams:
+            item = s["args"]["item"]
+            mbs = [m for m in tr.spans("microbatch") if _within(m, s)]
+            assert [(m["args"]["item"], m["args"]["part"]) for m in mbs] \
+                == [(item, 0), (item, 1)]
+            # one key for the item, one per microbatch
+            assert len([k for k in tr.spans("key_fold")
+                        if _within(k, s)]) == 3
+            for m in mbs:
+                (sync,) = [t for t in tr.spans("theta_sync")
+                           if _within(t, m)]
+                assert sync["depth"] == m["depth"] + 1
+            for name in ("merge", "drain"):
+                (child,) = [c for c in tr.spans(name) if _within(c, s)]
+                assert child["depth"] == s["depth"] + 1
+
+    @pytest.mark.parametrize("path", ["fused", "deferred", "fleet"])
+    def test_host_syncs_match_hand_count(self, params, path):
+        obs = obs_mod.Obs()
+        batches = _batches([8, 8, 8])
+        if path == "fleet":
+            fe = FleetEngine(CFG, params, backend="pallas", seed=0, obs=obs,
+                             fused_stream=False)
+            fe.add_chip(0)
+            fe.add_chip(1)
+            for b in batches:
+                fe.serve([(0, b), (1, b)])
+            # the exact steps dispatch without blocking: one drain each
+            want = len(batches)
+        elif path == "fused":
+            eng = VisionEngine(CFG, params, backend="pallas", seed=0,
+                               obs=obs, microbatch=4, fused_stream=True)
+            list(eng.stream(batches))
+            # one guard wait per microbatch, one more per fallback re-run;
+            # the drains find no probe pending
+            want = 2 * len(batches) + eng.fused_fallback_count
+        else:
+            eng = VisionEngine(CFG, params, backend="device", seed=0,
+                               obs=obs, microbatch=4)
+            list(eng.stream(batches))
+            # both microbatches dispatch unblocked: one drain per item
+            want = len(batches)
+        assert obs.counter("serving_host_syncs_total").value == want
+
+    def test_host_syncs_of_blocking_stream_steps(self, params):
+        obs = obs_mod.Obs()
+        eng = VisionEngine(CFG, params, backend="device", seed=0, obs=obs,
+                           microbatch=4, sync_timing=True)
+        list(eng.stream(_batches([8, 8])))
+        assert obs.counter("serving_host_syncs_total").value == 4
+        eng.classify(_batches([4])[0])      # a single shot is no stream
+        assert obs.counter("serving_host_syncs_total").value == 4
+
+
+class TestCompileCounter:
+    def test_counts_program_loads_once_per_obs(self):
+        seen = []
+
+        def listen(event, duration, **kwargs):
+            if event == obs_mod.compiles.EVENT:
+                seen.append(event)
+
+        a, b = obs_mod.Obs(), obs_mod.Obs()
+
+        def count(o):
+            snap = o.registry.snapshot().get("jax_compiles_total")
+            return 0.0 if snap is None else snap["value"]
+
+        jax.monitoring.register_event_duration_secs_listener(listen)
+        try:
+            f = jax.jit(lambda x: x * 3.0 + 1.0)
+            a0, b0 = count(a), count(b)
+            f(np.ones((3, 11), np.float32)).block_until_ready()
+            fresh = len(seen)
+            assert fresh >= 1
+            # every live Obs counts each load once: no stacked listener
+            assert count(a) - a0 == fresh == count(b) - b0
+            f(np.ones((3, 11), np.float32)).block_until_ready()
+            assert len(seen) == fresh and count(a) - a0 == fresh
+            c = obs_mod.Obs()
+            f(np.ones((5, 11), np.float32)).block_until_ready()
+            assert count(a) - a0 == len(seen) == count(b) - b0
+            assert count(c) == len(seen) - fresh
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listen)
 
 
 class TestFleetObs:
