@@ -73,10 +73,12 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import ContextManager, Dict, Iterable, Iterator, List, Optional
+from typing import (ContextManager, Dict, Iterable, Iterator, List, Optional,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import sharding
@@ -603,6 +605,34 @@ _SUM_KEYS = ("wall_ms",)
 _CONSTANT_KEYS = ("sensor_latency_us", "sensor_fps")
 
 
+def _weights(sizes: Tuple[int, ...]) -> np.ndarray:
+    """The microbatches' frame-count weights in f32, summing to one."""
+    w = np.asarray(sizes, np.float32)
+    return w / w.sum()
+
+
+def _reduce(k: str, vals: List, w: np.ndarray, xp):
+    """Key ``k``'s batch value computed from its microbatch values:
+    ``xp`` is ``jnp`` inside the compiled merge and ``np`` on the host."""
+    if k in _CHANNEL_KEYS:
+        return xp.sum(xp.stack(vals) * w[:, None], axis=0)
+    if xp.ndim(vals[0]) >= 1:
+        return xp.concatenate(vals, axis=0)
+    if k.endswith("_min"):
+        return xp.min(xp.stack(vals))
+    if k.endswith("_max"):
+        return xp.max(xp.stack(vals))
+    return xp.sum(xp.stack(vals) * w)
+
+
+@functools.partial(jax.jit, static_argnames="sizes")
+def _merge_on_device(outs: List[Dict], sizes: Tuple[int, ...]) -> Dict:
+    """The device-array keys of a batch merged in one program, with the
+    weights as its constants: one compile per (keys, shapes, sizes)."""
+    w = _weights(sizes)
+    return {k: _reduce(k, [o[k] for o in outs], w, jnp) for k in outs[0]}
+
+
 def _merge_outputs(outs: List[Dict], sizes: List[int]) -> Dict:
     """Merge per-microbatch outputs into one batch-level dict.
 
@@ -615,15 +645,19 @@ def _merge_outputs(outs: List[Dict], sizes: List[int]) -> Dict:
     keys by min/max, everything else — means, rates, and per-frame
     energies — by a frame-count-WEIGHTED mean (the tail microbatch of a
     batch that does not divide evenly must not be over-weighted).
+
+    The keys whose values are device arrays are merged by ONE compiled
+    program (``_merge_on_device``); host scalars never go to the device
+    (a weighted mean of them is taken in f32 NumPy, a 0-d value), so a
+    call moves nothing between host and device.
     """
-    w = jnp.asarray(sizes, jnp.float32)
-    w = w / jnp.sum(w)
+    sizes = tuple(sizes)
+    w = _weights(sizes)
     merged: Dict = {}
+    on_device: List[str] = []
     for k in outs[0]:
         vals = [o[k] for o in outs]
-        if k in _CHANNEL_KEYS:
-            merged[k] = jnp.sum(jnp.stack(vals) * w[:, None], axis=0)
-        elif k in _CUMULATIVE_KEYS:
+        if k in _CUMULATIVE_KEYS:
             merged[k] = vals[-1]
         elif k in _EVENT_KEYS:
             merged[k] = max(float(v) for v in vals)
@@ -631,14 +665,15 @@ def _merge_outputs(outs: List[Dict], sizes: List[int]) -> Dict:
             merged[k] = sum(float(v) for v in vals)
         elif k in _CONSTANT_KEYS:
             merged[k] = vals[0]
-        elif getattr(vals[0], "ndim", 0) >= 1:
-            merged[k] = jnp.concatenate(vals, axis=0)
-        elif k.endswith("_min"):
-            merged[k] = jnp.min(jnp.stack(vals))
-        elif k.endswith("_max"):
-            merged[k] = jnp.max(jnp.stack(vals))
+        elif any(isinstance(v, jax.Array) for v in vals):
+            on_device.append(k)
+            merged[k] = None            # filled below, in key order
         else:
-            merged[k] = jnp.sum(jnp.stack(vals) * w)
+            merged[k] = _reduce(k, [np.asarray(v, np.float32) for v in vals],
+                                w, np)
+    if on_device:
+        merged.update(_merge_on_device(
+            [{k: o[k] for k in on_device} for o in outs], sizes))
     if "wall_ms" in merged:
         merged["throughput_fps"] = sum(sizes) / (merged["wall_ms"] / 1e3)
     return merged

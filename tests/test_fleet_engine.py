@@ -11,8 +11,10 @@ from repro.launch.mesh import make_host_mesh
 from repro.lifetime import DriftConfig, SchedulePolicy
 from repro.models import vision
 from repro.serving import FleetEngine, FleetSweepPolicy, VisionEngine
+from repro.serving import fleet as fleet_mod
 from repro.variation.calibrate import calibrate
 from repro.variation.chip import VariationConfig
+from test_serving_sharded import assert_merge_matches, eager_merge
 
 CFG = vision.VisionConfig(arch="vgg_tiny")
 VPROFILE = VariationConfig(sigma_logit_offset=0.4, sigma_pixel_offset=0.25,
@@ -214,6 +216,32 @@ class TestRaggedFleets:
         fe = FleetEngine(CFG, params, backend="pallas", seed=0)
         with pytest.raises(KeyError):
             fe.remove_chip(3)
+
+
+class TestRequestMerge:
+    def test_split_request_merges_as_eager_reference(self, params,
+                                                     monkeypatch):
+        """A request split over several work items is merged by the
+        compiled merge under the eager merge's rules: streaming, lifetime
+        and constant keys alike."""
+        calls = []
+        merge = fleet_mod._merge_outputs
+
+        def spy(outs, sizes):
+            merged = merge(outs, sizes)
+            calls.append((outs, sizes, merged))
+            return merged
+
+        monkeypatch.setattr(fleet_mod, "_merge_outputs", spy)
+        fe = FleetEngine(CFG, params, backend="pallas", seed=0,
+                         microbatch=2, chips_per_step=2, drift=DPROFILE)
+        for s in range(2):
+            fe.serve([(0, _frames(s + 1, 5)), (1, _frames(s + 5, 4))])
+        assert [c[1] for c in calls] == [[2, 2, 1], [2, 2]] * 2
+        for outs, sizes, merged in calls:
+            assert {"stream_fused", "lifetime_age_frames",
+                    "sensor_fps"} <= set(merged)
+            assert_merge_matches(merged, eager_merge(outs, sizes), sizes)
 
 
 class TestMaintenanceSweep:

@@ -5,9 +5,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs as obs_mod
 from repro.launch.mesh import make_host_mesh
 from repro.models import vision
 from repro.serving import VisionEngine
+from repro.serving import vision as serving_vision
 
 
 def _engine_fixture(backend="pallas", **kw):
@@ -139,6 +141,127 @@ class TestStreamEdgeCases:
         assert out["channel_rates"].shape == (32,)   # C, not 3 chunks x C
         assert 0.0 <= float(jnp.min(out["channel_rates"]))
         assert float(jnp.max(out["channel_rates"])) <= 1.0
+
+
+def eager_merge(outs, sizes):
+    """The per-key eager merge that the compiled one replaced, kept
+    verbatim as the reference for its rules."""
+    sv = serving_vision
+    w = jnp.asarray(sizes, jnp.float32)
+    w = w / jnp.sum(w)
+    merged = {}
+    for k in outs[0]:
+        vals = [o[k] for o in outs]
+        if k in sv._CHANNEL_KEYS:
+            merged[k] = jnp.sum(jnp.stack(vals) * w[:, None], axis=0)
+        elif k in sv._CUMULATIVE_KEYS:
+            merged[k] = vals[-1]
+        elif k in sv._EVENT_KEYS:
+            merged[k] = max(float(v) for v in vals)
+        elif k in sv._SUM_KEYS:
+            merged[k] = sum(float(v) for v in vals)
+        elif k in sv._CONSTANT_KEYS:
+            merged[k] = vals[0]
+        elif getattr(vals[0], "ndim", 0) >= 1:
+            merged[k] = jnp.concatenate(vals, axis=0)
+        elif k.endswith("_min"):
+            merged[k] = jnp.min(jnp.stack(vals))
+        elif k.endswith("_max"):
+            merged[k] = jnp.max(jnp.stack(vals))
+        else:
+            merged[k] = jnp.sum(jnp.stack(vals) * w)
+    if "wall_ms" in merged:
+        merged["throughput_fps"] = sum(sizes) / (merged["wall_ms"] / 1e3)
+    return merged
+
+
+def assert_merge_matches(got, ref, sizes):
+    """``got`` holds every key of ``ref`` at its shape and dtype. Keys
+    whose rule picks, joins or adds values are bit-identical; so is every
+    key of a two-microbatch merge. A frame-weighted mean over three or
+    more microbatches may differ in the last f32 bits (``rtol=1e-6``):
+    one fused multiply-reduce rounds in another order than the eager
+    multiply, then sum."""
+    sv = serving_vision
+    exact = (sv._CUMULATIVE_KEYS + sv._EVENT_KEYS + sv._SUM_KEYS
+             + sv._CONSTANT_KEYS + ("throughput_fps",))
+    assert set(got) == set(ref)
+    for k in ref:
+        g, r = np.asarray(got[k]), np.asarray(ref[k])
+        assert (g.shape, g.dtype) == (r.shape, r.dtype), k
+        mean = k in sv._CHANNEL_KEYS or (
+            r.ndim == 0 and k not in exact
+            and not k.endswith(("_min", "_max")))
+        if len(sizes) == 2 or not mean:
+            np.testing.assert_array_equal(g, r, err_msg=k)
+        else:
+            np.testing.assert_allclose(g, r, rtol=1e-6, err_msg=k)
+
+
+def _microbatch_outputs(sizes, extras, seed=0):
+    """Outputs shaped as a stream's microbatches give them: device arrays
+    for the step's own keys, Python floats for the host telemetry and the
+    lifetime keys."""
+    rng = np.random.default_rng(seed)
+
+    def dev(x):
+        return jnp.asarray(x, jnp.float32)
+
+    outs = []
+    for j, n in enumerate(sizes):
+        o = {"labels": jnp.asarray(rng.integers(0, 10, n), jnp.int32),
+             "probs": dev(rng.random((n, 10))),
+             "channel_rates": dev(rng.random(32)),
+             "p2m_sparsity": dev(rng.random()),
+             "read_energy_pj": dev(1e3 * rng.random()),
+             "theta": dev(rng.random()),
+             "v_conv_min": dev(-rng.random()),
+             "v_conv_max": dev(rng.random()),
+             "wall_ms": 1.0 + 5.0 * rng.random(),
+             "throughput_fps": 1e3 * rng.random(),
+             "sensor_latency_us": 61.3,
+             "sensor_fps": 1e6 / 61.3}
+        if "stream" in extras:
+            o["theta_used"] = dev(rng.random())
+            o["stream_fused"] = 0.0 if j == 0 else 1.0
+            o["stream_theta_drift"] = 0.0 if j == 0 else 0.01 * rng.random()
+        if "lifetime" in extras:
+            o["lifetime_age_frames"] = float(sum(sizes[:j + 1]))
+            o["lifetime_recal_count"] = float(j // 2)
+            o["lifetime_recal_fired"] = 1.0 if j == 1 else 0.0
+            o["lifetime_rate_err"] = float(rng.random())
+            o["lifetime_recal_energy_pj"] = 12.5 * (j // 2)
+        outs.append(o)
+    return outs
+
+
+class TestMergeProgram:
+    """A stream item's microbatch outputs are merged by one compiled
+    program, under the same rules as the eager per-key merge."""
+
+    @pytest.mark.parametrize("extras", [("stream",), ("lifetime",),
+                                        ("stream", "lifetime")])
+    @pytest.mark.parametrize("sizes", [(2, 2), (2, 2, 1), (2, 2, 2, 2, 1)])
+    def test_matches_eager_merge(self, sizes, extras):
+        outs = _microbatch_outputs(sizes, extras, seed=len(sizes))
+        assert_merge_matches(serving_vision._merge_outputs(outs, list(sizes)),
+                             eager_merge(outs, list(sizes)), sizes)
+
+    def test_one_program_for_same_shape_items(self):
+        obs = obs_mod.Obs()
+        _, _, eng = _engine_fixture(microbatch=2, obs=obs)
+
+        def compiles():
+            snap = obs.registry.snapshot().get("jax_compiles_total")
+            return 0.0 if snap is None else snap["value"]
+
+        serving_vision._merge_on_device.clear_cache()
+        items = eng.stream([_frames(b=4, seed=s) for s in (1, 2, 3)])
+        next(items)
+        before = compiles()
+        assert len(list(items)) == 2
+        assert serving_vision._merge_on_device._cache_size() == 1
+        assert compiles() == before
 
 
 class TestServingTelemetry:
