@@ -1,30 +1,27 @@
-"""The one traffic generator: closed and open loops over a VisionEngine.
+"""The one traffic generator: closed and open loops over a kind's server.
 
 A traffic file (``traffic/<mix>.json``) names its ``loop`` and parameters;
 a cell file (``cells/<workload>.json``), where there is one, overrides them
-for one cell (the open loop's fixed rate). Both loops feed ONE long-lived
-``engine.stream()`` iterable, so the engine's carried threshold persists
-across the window as in deployment, and fetch each item's labels to the
-host.
+for one cell (the open loop's fixed rate). Both loops drive the server of
+the configuration's kind (``kinds/``), which serves one item at a time and
+brings its outputs to the host.
 
 closed  one client sends batches of ``batch_microbatches`` microbatches
-        back to back: the next when the previous one's labels are on the
-        host; frames from a pool made from the seed, in a fixed cycle. The
-        engine splits each batch into microbatches and merges their
-        outputs into one result.
-open    single-frame requests from many cameras arrive on a schedule made
+        back to back: the next when the previous one's outputs are on the
+        host; inputs from a pool made from the seed, in a fixed cycle.
+open    single-input requests from many clients arrive on a schedule made
         from the seed (counter-hash Poisson gaps); the harness admits them
-        into windows that close at ``window_frames`` frames or
+        into windows that close at ``window_frames`` requests or
         ``window_ms`` after their first arrival, pads a short window with
-        cyclic copies of its own frames (neither counted nor compared) and
-        serves it as one stream item. Latency runs from a request's
-        scheduled arrival to its label on the host.
+        cyclic copies of its own inputs (neither counted nor compared) and
+        serves it as one item. Latency runs from a request's scheduled
+        arrival to its output on the host.
 
-Every item records what the correctness check needs of a seeded sample of
-items (``Sample``, one per microbatch): the stream index and the
-microbatch's place in the item (which fix the engine's rng key), its
-frames' pool indices, its own outputs and the rows of the served result
-that are its answers.
+Every item offers what the correctness check needs of a seeded sample of
+items (``Sample``, one per microbatch): the item's index and the
+microbatch's place in it (which fix the engine's rng key), its inputs'
+pool indices, its own outputs and the rows of the served result that are
+its answers.
 """
 from __future__ import annotations
 
@@ -34,11 +31,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-from bench import system
 
 now = time.perf_counter
 
@@ -89,8 +82,7 @@ class Sample:
     frames: np.ndarray         # pool indices of the microbatch's frames
     real: int                  # how many leading frames were requests
     row0: int                  # its first row in the item's served result
-    out: Dict                  # its own outputs; "probs" is the served
-                               # result of the whole item
+    out: Dict                  # what the check reads of its outputs
 
 
 class Reservoir:
@@ -112,37 +104,15 @@ class Reservoir:
             self.items[j] = make()
 
 
-_KEEP = ("theta", "theta_used", "channel_rates", "stream_fused")
-
-
-def item_samples(index: int, idx: np.ndarray, real: int, out: Dict,
-                 parts: List[Dict]) -> List[Sample]:
-    """The samples of one served item: one per microbatch, each with its
-    own outputs (``parts``, in order) and the item's served probabilities
-    (``out``), of which rows ``row0 ..`` are its answers."""
-    mb = len(idx) // len(parts)
-    return [Sample(index, j, len(parts), idx[j * mb:(j + 1) * mb],
-                   max(0, min(mb, real - j * mb)), j * mb,
-                   {"probs": out["probs"],
-                    **{k: p[k] for k in _KEEP if k in p}})
-            for j, p in enumerate(parts)]
-
-
-class Feed:
-    """The iterable ``engine.stream()`` pulls from: the harness sets the
-    next item before asking for its result."""
-
-    def __init__(self):
-        self.frames: Optional[jax.Array] = None
-
-    def __iter__(self):
-        return self
-
-    def __next__(self):
-        frames, self.frames = self.frames, None
-        if frames is None:
-            raise StopIteration
-        return frames
+def item_samples(index: int, idx: np.ndarray, real: int,
+                 outs: List[Dict]) -> List[Sample]:
+    """The samples of one served item: one per microbatch, with the
+    outputs of it the check reads (``outs``, in order); microbatch ``j``
+    answers rows ``j * mb ..`` of the item's served result."""
+    mb = len(idx) // len(outs)
+    return [Sample(index, j, len(outs), idx[j * mb:(j + 1) * mb],
+                   max(0, min(mb, real - j * mb)), j * mb, out)
+            for j, out in enumerate(outs)]
 
 
 @dataclass
@@ -150,7 +120,7 @@ class Record:
     """What one run's window produced, for the metric readers."""
     setup_s: float = 0.0         # process start to the first timed step
     window_s: float = 0.0
-    frames: int = 0              # frames whose labels reached the host
+    frames: int = 0              # requests whose outputs reached the host
     steps: int = 0               # stream items served in the window
     stream_steps: int = 0        # microbatches the stream served in all
     attempted: int = 0
@@ -164,54 +134,12 @@ class Record:
     samples: List[Sample] = field(default_factory=list)
 
 
-class Server:
-    """The engine behind one stream. The frame pool is kept whole (the
-    closed loop cuts it, once, into batch-sized blocks) and as one row per
-    frame (what the open loop gathers a window from), so an item adds no
-    device work of the harness's own beyond a gather of its own frames.
-    ``parts`` receives each microbatch's own outputs as the engine makes
-    them (``system.record_microbatches``)."""
-
-    def __init__(self, engine, pool: jax.Array, mb: int):
-        self.engine, self.mb, self.pool = engine, mb, pool
-        self.pool_n, self.frame_shape = pool.shape[0], pool.shape[1:]
-        self.rows = pool.reshape(self.pool_n, -1)
-        self.parts = system.record_microbatches(engine)
-        self.feed = Feed()
-        self.outs = engine.stream(self.feed)
-        self.index = 0           # stream items served
-        self.microbatches = 0    # microbatches the stream served
-        shape = (mb,) + tuple(self.frame_shape)
-        self._gather = jax.jit(
-            lambda rows, i: jnp.take(rows, i, axis=0).reshape(shape))
-
-    def blocks(self, n: int) -> List[jax.Array]:
-        """The pool cut into consecutive blocks of ``n`` frames."""
-        return [self.pool[i:i + n] for i in range(0, self.pool_n, n)]
-
-    def serve(self, frames: jax.Array):
-        """Serve one stream item; returns (labels on the host, the served
-        result, each microbatch's own outputs, stream index)."""
-        self.feed.frames = frames
-        self.parts.clear()
-        out = next(self.outs)
-        labels = np.asarray(out["labels"])
-        parts = list(self.parts)
-        i, self.index = self.index, self.index + 1
-        self.microbatches += len(parts)
-        return labels, out, parts, i
-
-    def window(self, idx: np.ndarray) -> jax.Array:
-        """Pool frames ``idx`` (length mb) as one microbatch."""
-        return self._gather(self.rows, jnp.asarray(idx, jnp.int32))
-
-
-def _finish(rec: Record, server: Server, res: Reservoir) -> None:
+def _finish(rec: Record, server, res: Reservoir) -> None:
     rec.stream_steps = server.microbatches
     rec.samples = [s for item in res.items for s in item]
 
 
-def closed(server: Server, traffic: Dict, seconds: float, seed: int,
+def closed(server, traffic: Dict, seconds: float, seed: int,
            sample_steps: int, tracer=None, t_origin: float = 0.0) -> Record:
     """Batches of ``batch_microbatches`` microbatches back to back for
     ``seconds``; the pool is cycled in batch-sized blocks."""
@@ -235,7 +163,7 @@ def closed(server: Server, traffic: Dict, seconds: float, seed: int,
         labels, out, parts, i = server.serve(blocks[b])
         rec.steps += 1
         rec.frames += int(labels.shape[0])
-        res.offer(lambda: item_samples(i, idx, n, out, parts))
+        res.offer(lambda: server.samples(i, idx, n, out, parts))
     rec.window_s = now() - t0
     if tracer is not None:
         rec.traced = tracer.stop(rec.window_s)
@@ -244,7 +172,7 @@ def closed(server: Server, traffic: Dict, seconds: float, seed: int,
     return rec
 
 
-def open_loop(server: Server, traffic: Dict, seconds: float, seed: int,
+def open_loop(server, traffic: Dict, seconds: float, seed: int,
               sample_steps: int, tracer=None, t_origin: float = 0.0
               ) -> Record:
     """Poisson single-frame arrivals at the cell's fixed rate, admitted
@@ -300,7 +228,7 @@ def open_loop(server: Server, traffic: Dict, seconds: float, seed: int,
         head += take
         rec.steps += 1
         rec.frames += take
-        res.offer(lambda: item_samples(i, idx, take, out, parts))
+        res.offer(lambda: server.samples(i, idx, take, out, parts))
     rec.window_s = max(now() - t0, seconds)
     if tracer is not None:
         rec.traced = tracer.stop(rec.window_s)

@@ -8,8 +8,8 @@ from bench import program_trace
 
 
 def read(ctx):
-    program = program_trace.of_run(ctx, __file__)
-    if not program or program["syncs"] is None \
-            or program["microbatches"] == 0:
+    program = program_trace.of_run(ctx)
+    if not program or program["marks"].get("syncs") is None \
+            or program["span_n"].get("microbatch", 0) == 0:
         return None
-    return program["syncs"] / program["microbatches"]
+    return program["marks"]["syncs"] / program["span_n"]["microbatch"]

@@ -5,7 +5,7 @@ from bench import program_trace
 
 
 def read(ctx):
-    program = program_trace.of_run(ctx, __file__)
+    program = program_trace.of_run(ctx)
     if not program or "merge" not in program["span_s"] \
             or program["window_s"] <= 0:
         return None

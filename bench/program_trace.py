@@ -1,14 +1,17 @@
 """From a profiler trace of the window to the program's own stages.
 
 ``trace_reduce`` names device time by kernel and idle gaps by whatever the
-host thread was doing; this module names both by the program's stages:
+host thread was doing; this module names both by the program's stages, as
+the configuration's kind (``kinds/``) names them:
 
-scope_s   device seconds of the operations under each named scope of the
-          model (``models/vision.py``): ``p2m_frontend``, ``backbone`` (all
-          of it) and each ``backbone/<layer>`` (``conv{i}``, or a ResNet
-          block), ``head``; ``""`` for operations under none
-span_s    host seconds inside each engine span (``serving/vision.py``):
-          which spans the program has at all
+scope_s   device seconds of the operations under each of the model's
+          device scopes (the kind's ``SCOPES``: for ``p2m_vgg``
+          ``p2m_frontend``, each ``backbone/<layer>``, ``head``), a stage's
+          seconds (``backbone``) including its layers'; ``""`` for
+          operations under none
+span_s    host seconds inside each of the engine's spans (the kind's
+          ``SPANS``): which spans the program has at all
+span_n    how many of each span the window holds
 idle_s    the idle gaps of ``trace_reduce.reduce`` (the same gaps), each put
           down to the innermost engine span that covers it, or to
           ``harness`` when none does
@@ -23,167 +26,65 @@ same device. A program without these scopes or spans (an older one) gives
 empty tables, never an error. Checked on synthetic events and on a
 recorded chip trace in ``tests/``.
 
-``of_run`` is what the metric readers call: once per traced run it reads
-the window's ``.xplane.pb`` (which ``trace_reduce.Window`` leaves under
-``bench/traces``), the step programs' HLO (``step_programs``) and the
-program's own counts, reduces them, prints the tables on standard error
-and keeps the result in the readers' shared context. It adds
-
-compiles     the program's ``jax_compile`` marks inside the window: each
-             increment of its ``jax_compiles_total`` leaves one on the
-             profiler's clock (None where the program makes none)
-syncs        the same of ``host_sync``, the mark of each increment of
-             ``serving_host_syncs_total``
-microbatches the engine's ``microbatch`` spans inside the window: one per
-             microbatch served
+``of_window`` is what ``trace_reduce.Window.stop`` calls, once per traced
+run, on the events it has already loaded and the served engine's own step
+programs (the kind server's ``step_programs``). It adds, for each of the
+kind's ``MARKS`` (``compiles``: a mark per program load; ``syncs``: a mark
+per host sync), how many the window holds, under ``marks`` (None where
+the program makes no such mark). The metric readers find the result with
+``of_run``.
 """
 from __future__ import annotations
 
 import bisect
-import glob
 import heapq
-import os
 import re
-import sys
-import time
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
-
-import jax
-import jax.numpy as jnp
 
 from bench import trace_reduce
 
-# the engine's spans, outermost first
-SPANS = ("stream", "microbatch", "key_fold", "theta_sync", "merge", "drain")
 HARNESS = "harness"
-# the program's marks of a program load and of a host sync
-COMPILE_MARK = "jax_compile"
-SYNC_MARK = "host_sync"
-_SCOPE = re.compile(
-    r"(?:^|/)(p2m_frontend|head|backbone(?:/(?:conv\d+|s\d+b\d+))?)(?=/|$)")
 _HLO_MODULE = re.compile(r"^HloModule ([^\s,]+)", re.M)
 _HLO_OP = re.compile(
     r'^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*?op_name="([^"]*)"', re.M)
 _RUN_ID = re.compile(r"\(.*\)$")
 
 
-def scope_of(op_name: str) -> str:
-    """The model scope of an op name: the innermost of ``p2m_frontend``,
-    ``backbone[/<layer>]``, ``head``, or ``""``."""
-    found = _SCOPE.findall(op_name)
+def scope_of(op_name: str, pattern: re.Pattern) -> str:
+    """The innermost model scope ``pattern`` finds in an op name, or
+    ``""``."""
+    found = pattern.findall(op_name)
     return found[-1] if found else ""
 
 
-def scopes(texts: Iterable[str]) -> Dict[str, Dict[str, str]]:
+def scopes(texts: Iterable[str], pattern: re.Pattern
+           ) -> Dict[str, Dict[str, str]]:
     """{program: {instruction: scope}} from optimized HLO texts."""
     out: Dict[str, Dict[str, str]] = {}
     for text in texts:
         m = _HLO_MODULE.search(text)
         if m:
-            out[m.group(1)] = {name: scope_of(op)
+            out[m.group(1)] = {name: scope_of(op, pattern)
                                for name, op in _HLO_OP.findall(text)}
     return out
 
 
-def load_events(path: str, device_ids: Sequence[int]) -> Dict:
-    """``trace_reduce.load_events``'s tables, and "modules": {id:
-    [Event]}, each device's program runs (its "XLA Modules" line)."""
-    from jax.profiler import ProfileData
-    data = ProfileData.from_file(path)
-    device: Dict[int, List] = {}
-    modules: Dict[int, List] = {}
-    host: List = []
-    for plane in data.planes:
-        m = trace_reduce._DEVICE_PLANE.match(plane.name)
-        if m and int(m.group(1)) in device_ids:
-            i = int(m.group(1))
-            for line in plane.lines:
-                if line.name == "XLA Modules":
-                    modules.setdefault(i, []).extend(
-                        (e.name, e.start_ns, e.duration_ns, "")
-                        for e in line.events)
-                elif line.name == "XLA Ops":
-                    device.setdefault(i, []).extend(
-                        (trace_reduce._short(e.name), e.start_ns,
-                         e.duration_ns, trace_reduce._scope_of(e.name,
-                                                               e.stats))
-                        for e in line.events)
-        elif plane.name == "/host:CPU":
-            for line in plane.lines:
-                if line.name.startswith("python"):
-                    host.extend((e.name, e.start_ns, e.duration_ns, "")
-                                for e in line.events)
-    return {"device": device, "host": host, "modules": modules}
-
-
-def step_programs(cfg: Dict) -> List[str]:
-    """The optimized HLO text of the engine's step programs at the
-    configuration's microbatch, for an engine built on weights' shapes
-    alone: the served engine's programs, found in the compile caches. A
-    program without the model's scopes is left out uncompiled."""
-    from bench import inputs, system
-    params = jax.eval_shape(lambda: inputs.weights(cfg, 0))
-    eng = system.engine(cfg, params, 0)
-    frames = jax.ShapeDtypeStruct(
-        (cfg["microbatch"], cfg["in_hw"], cfg["in_hw"],
-         cfg["p2m"]["in_channels"]), jnp.float32)
-    key = jax.random.PRNGKey(0)
-    lowered = [eng._step.lower(params, frames, key)]
-    if eng.backend == "pallas":
-        lowered.append(eng._fused_step.lower(
-            params, frames, key, jax.ShapeDtypeStruct((), jnp.float32)))
-    return [low.compile().as_text() for low in lowered
-            if _SCOPE.search(low.as_text(debug_info=True))]
-
-
-def _marks() -> Tuple[bool, bool]:
-    """Whether the program marks its program loads and its host syncs
-    (an older one does neither)."""
-    try:
-        from repro.obs import compiles
-        from repro.serving import vision
-    except ImportError:
-        return False, False
-    return (getattr(compiles, "MARK", None) == COMPILE_MARK,
-            getattr(vision, "HOST_SYNC_MARK", None) == SYNC_MARK)
-
-
-def of_run(ctx: Dict, reader_file: str) -> Optional[Dict]:
-    """The traced run's reduction by program stage (``reduce``, with
-    ``compiles``, ``syncs``, ``microbatches`` and ``reduce_s``), made at
-    the first call and kept in ``ctx`` for the other readers; None where
-    the window ran nothing on a device (no device plane, as on a CPU).
-    ``reader_file`` is the calling reader's ``__file__``, in
-    ``bench/metrics`` of the checkout whose ``bench/traces`` holds the
-    window's trace."""
-    if "program" not in ctx:
-        ctx["program"] = _of_run(ctx, os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(reader_file)))))
-    return ctx["program"]
-
-
-def _of_run(ctx: Dict, root: str) -> Optional[Dict]:
-    traced = ctx["rec"].traced
-    if not traced or not traced.get("busy_s"):
-        return None
-    paths = sorted(glob.glob(os.path.join(
-        root, "bench", "traces", "plugins", "profile", "*", "*.xplane.pb")))
-    if not paths:
-        return None
-    t = time.perf_counter()
-    ids = [d.id for d in jax.devices()[:ctx["chips"]]]
-    events = load_events(paths[-1], ids)
-    out = reduce(events, traced["window_s"],
-                 scopes(step_programs(ctx["config"])))
+def of_window(events: Dict, window_s: float, kind,
+              texts: Sequence[str]) -> Dict:
+    """The traced window's reduction by program stage (``reduce``), with
+    the count of each of the kind's marks in it."""
+    out = reduce(events, window_s, scopes(texts, kind.SCOPES), kind.SPANS)
     names = [e[0] for e in events["host"]]
-    loads, syncs = _marks()
-    out["compiles"] = names.count(COMPILE_MARK) if loads else None
-    out["syncs"] = names.count(SYNC_MARK) if syncs else None
-    out["microbatches"] = names.count("microbatch")
-    out["reduce_s"] = time.perf_counter() - t
-    for line in report(out):
-        print(line, file=sys.stderr)
+    out["marks"] = {reading: names.count(mark) if mark else None
+                    for reading, mark in kind.MARKS.items()}
     return out
+
+
+def of_run(ctx: Dict) -> Optional[Dict]:
+    """The traced run's reduction by program stage, as ``Window.stop``
+    made it; None where the window ran nothing on a device (no device
+    plane, as on a CPU) or was not traced."""
+    return (ctx["rec"].traced or {}).get("program")
 
 
 def _cover(events: Sequence[Tuple[str, float, float, str]],
@@ -199,9 +100,11 @@ def _cover(events: Sequence[Tuple[str, float, float, str]],
 
 
 def reduce(events: Dict, window_s: float,
-           programs: Dict[str, Dict[str, str]], top: int = 5) -> Dict:
-    """scope_s, span_s, idle_s and top_gaps from ``trace_reduce``'s
-    events and the programs' scope tables (``scopes``)."""
+           programs: Dict[str, Dict[str, str]], span_names: Sequence[str],
+           top: int = 5) -> Dict:
+    """scope_s, span_s, span_n, idle_s and top_gaps from ``trace_reduce``'s
+    events, the programs' scope tables (``scopes``) and the engine's span
+    names."""
     scope_s: Dict[str, float] = {}
     for i, evs in events["device"].items():
         runs = sorted(events.get("modules", {}).get(i, []),
@@ -211,13 +114,16 @@ def reduce(events: Dict, window_s: float,
             program = _RUN_ID.sub("", _cover(runs, run_starts, s, 1))
             scope = programs.get(program, {}).get(name, "")
             scope_s[scope] = scope_s.get(scope, 0.0) + d * 1e-9
-            if scope.startswith("backbone/"):
-                scope_s["backbone"] = scope_s.get("backbone", 0.0) + d * 1e-9
+            if "/" in scope:        # a layer counts in its stage too
+                stage = scope.split("/", 1)[0]
+                scope_s[stage] = scope_s.get(stage, 0.0) + d * 1e-9
     host = sorted(events["host"], key=lambda e: e[1])
-    spans = [e for e in host if e[0] in SPANS]
+    spans = [e for e in host if e[0] in span_names]
     span_s: Dict[str, float] = {}
+    span_n: Dict[str, int] = {}
     for name, _, d, _ in spans:
         span_s[name] = span_s.get(name, 0.0) + d * 1e-9
+        span_n[name] = span_n.get(name, 0) + 1
     span_starts = [e[1] for e in spans]
     host_starts = [e[1] for e in host]
     idle_s: Dict[str, float] = {}
@@ -229,7 +135,8 @@ def reduce(events: Dict, window_s: float,
         idle_s[span] = idle_s.get(span, 0.0) + (e - s) * 1e-9
         gaps.append(((e - s) * 1e-9, span,
                      trace_reduce._host_label(host, host_starts, mid)))
-    return {"scope_s": scope_s, "span_s": span_s, "idle_s": idle_s,
+    return {"scope_s": scope_s, "span_s": span_s, "span_n": span_n,
+            "idle_s": idle_s,
             "window_s": window_s,
             "top_gaps": [list(g) for g in heapq.nlargest(
                 top, gaps, key=lambda g: g[0])]}
@@ -257,9 +164,8 @@ def report(program: Dict) -> List[str]:
     for secs, span, host in program["top_gaps"]:
         out.append(f"idle gap {secs * 1e3:.4f} ms under span {span}, "
                    f"host event {host}")
-    if "reduce_s" in program:
-        out.append(f"program trace reduced in {program['reduce_s']:.3f} s; "
-                   f"programs loaded in the window: {program['compiles']}; "
-                   f"host syncs: {program['syncs']} over {program['microbatches']} "
-                   "microbatches")
+    out.append("marks in the window: " + ", ".join(
+        f"{k} {v}" for k, v in sorted(program.get("marks", {}).items()))
+               + "; spans: " + ", ".join(
+                   f"{k} {v}" for k, v in sorted(program["span_n"].items())))
     return out

@@ -4,13 +4,15 @@
 
 Everything a cell is made of is found by name: the cell in
 ``BENCHMARK.json``, its configuration in ``bench/configs/<config>.json``,
-its traffic in ``bench/traffic/<traffic>.json`` (overridden by
+the configuration's kind in ``bench/kinds/<kind>.py`` (by the file's
+``"kind"``), its traffic in ``bench/traffic/<traffic>.json`` (overridden by
 ``bench/cells/<cell>.json`` where that exists), and each metric's reader in
-``bench/metrics/<metric>.py``. A run builds weights and frames from the
-seed, warms up every shape the window uses (set-up), serves for
-``--seconds``, then checks a seeded sample of what it served against the
-plain reference. ``--trace 1`` runs the window under the profiler and
-reports the cell's per-layer metrics instead of its end-to-end ones.
+``bench/metrics/<metric>.py``. A run builds the kind's server from the
+seed (weights, inputs, engine), warms up every shape the window uses
+(set-up), serves for ``--seconds``, then checks a seeded sample of what it
+served against the kind's plain reference. ``--trace 1`` runs the window
+under the profiler and reports the cell's per-layer metrics instead of its
+end-to-end ones.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown`` when
@@ -28,7 +30,9 @@ import argparse  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import sys  # noqa: E402
+import zlib  # noqa: E402
 from typing import Callable, Dict, List, Optional  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -38,11 +42,11 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
 
 import jax  # noqa: E402
 
-# pool of frames the closed loop cycles through / requests draw from,
-# in microbatches
-POOL_BLOCKS = 8
 # microbatches the correctness check recomputes
 SAMPLE_STEPS = 24
+# what a kind module must provide (``bench/kinds/__init__.py``)
+KIND_NAMES = ("Server", "readings", "limits", "work", "KERNELS", "SCOPES",
+              "SPANS", "MARKS")
 
 
 def _load(path: str) -> Dict:
@@ -50,8 +54,34 @@ def _load(path: str) -> Dict:
         return json.load(f)
 
 
+def load_kind(root: str, name) -> object:
+    """The module ``bench/kinds/<name>.py`` of this checkout, loaded once
+    per process; an unknown kind, or a module that lacks what a kind
+    provides, is an error that names it."""
+    if not isinstance(name, str) or not re.fullmatch(r"\w+", name):
+        raise SystemExit(f"run.py: the configuration names no kind "
+                         f"(\"kind\": {name!r})")
+    path = os.path.join(root, "bench", "kinds", name + ".py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"run.py: unknown kind {name!r}: there is no "
+                         f"bench/kinds/{name}.py")
+    mod_name = f"bench_kind_{name}_{zlib.crc32(path.encode()):08x}"
+    mod = sys.modules.get(mod_name)
+    if mod is None:
+        spec = importlib.util.spec_from_file_location(mod_name, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[mod_name] = mod
+    missing = [k for k in KIND_NAMES if not hasattr(mod, k)]
+    if missing:
+        raise SystemExit(f"run.py: bench/kinds/{name}.py is no kind: it "
+                         f"lacks {', '.join(missing)}")
+    return mod
+
+
 def load_cell(root: str, workload: str) -> Dict:
-    """The cell's entry, configuration, traffic and metric lists."""
+    """The cell's entry, configuration, its kind's module, traffic and
+    metric lists."""
     bench = _load(os.path.join(root, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
@@ -65,9 +95,9 @@ def load_cell(root: str, workload: str) -> Dict:
 
     def mine(m):
         return "workloads" not in m or workload in m["workloads"]
-    return {"cell": cell, "traffic": traffic,
-            "config": _load(os.path.join(d, "configs",
-                                         cell["config"] + ".json")),
+    cfg = _load(os.path.join(d, "configs", cell["config"] + ".json"))
+    return {"cell": cell, "traffic": traffic, "config": cfg,
+            "kind": load_kind(root, cfg.get("kind")),
             "end_to_end": [m for m in bench["end_to_end"] if mine(m)],
             "per_layer": [m for m in bench["per_layer"] if mine(m)]}
 
@@ -123,25 +153,20 @@ def memory_peak(devs) -> Optional[int]:
 
 def serve(spec: Dict, seed: int, seconds: float, trace: bool, root: str,
           devs) -> Dict:
-    """Set-up, the window and the trace: everything the program runs."""
-    from bench import inputs, loops, system, trace_reduce
-    cfg, traffic = spec["config"], spec["traffic"]
-    mb = cfg["microbatch"]
-    params = inputs.weights(cfg, seed)
-    pool = inputs.frame_pool(cfg, POOL_BLOCKS * mb, seed)
-    obs = system.make_obs() if trace else None
-    eng = system.engine(cfg, params, inputs.engine_seed(seed), obs)
-    server = loops.Server(eng, pool, mb)
+    """Set-up, the window and the trace: everything the program runs.
+    The loop is the kind's own where it brings one for the traffic's
+    ``loop``, else the shared one."""
+    from bench import loops, trace_reduce
+    kind, traffic = spec["kind"], spec["traffic"]
+    server = kind.Server(spec["config"], seed, trace)
     tracer = (trace_reduce.Window(os.path.join(root, "bench", "traces"),
-                                  devs) if trace else None)
-    rec = loops.LOOPS[traffic["loop"]](server, traffic, seconds, seed,
-                                       SAMPLE_STEPS, tracer=tracer,
-                                       t_origin=T_START)
-    if obs is not None:
-        rec.counters = {k: system.counter(obs, k)
-                        for k in ("serving_fused_steps_total",
-                                  "serving_fused_fallback_total")}
-    return {"rec": rec, "params": params, "pool": pool}
+                                  devs, kind, server) if trace else None)
+    loop = {**loops.LOOPS, **getattr(kind, "LOOPS", {})}[traffic["loop"]]
+    rec = loop(server, traffic, seconds, seed, SAMPLE_STEPS, tracer=tracer,
+               t_origin=T_START)
+    if trace:
+        rec.counters = server.counters()
+    return {"rec": rec, "server": server}
 
 
 def main(argv=None, root: str = ROOT,
@@ -156,17 +181,17 @@ def main(argv=None, root: str = ROOT,
     devs = require(spec["cell"]["chips"])
     peak = peak_of(root, devs[0].device_kind)
     use_compile_cache(root)
-    from bench import correct
+    from bench import correct, program_trace
+    kind, cfg = spec["kind"], spec["config"]
     ran = serve(spec, args.seed, args.seconds, bool(args.trace), root, devs)
     rec = ran["rec"]
     mem = memory_peak(devs)
     t_check = time.perf_counter()
-    read = correct.readings(spec["config"], ran["params"], ran["pool"],
-                            args.seed, rec.samples)
+    read = kind.readings(cfg, ran["server"], args.seed, rec.samples)
     print(f"reference check: {len(rec.samples)} microbatches in "
           f"{time.perf_counter() - t_check:.3f} s", file=sys.stderr)
-    ok, checks = correct.judge(read, correct.limits(spec["config"]))
-    ctx = {"rec": rec, "config": spec["config"], "peak": peak,
+    ok, checks = correct.judge(read, kind.limits(cfg))
+    ctx = {"rec": rec, "config": cfg, "kind": kind, "peak": peak,
            "chips": len(devs)}
     wanted = spec["per_layer"] if args.trace else spec["end_to_end"]
     metrics = {}
@@ -182,6 +207,11 @@ def main(argv=None, root: str = ROOT,
         device["busy_s"] = rec.traced["busy_s"]
         device["window_s"] = rec.traced["window_s"]
         result["breakdown"] = rec.traced["breakdown"]
+        program = program_trace.of_run(ctx)
+        for line in program_trace.report(program) if program else ():
+            print(line, file=sys.stderr)
+        print(f"trace read once and reduced in {rec.traced['reduce_s']:.3f}"
+              " s", file=sys.stderr)
     if rec.late_s:
         late = sorted(rec.late_s)
         print(f"generator late: p50 {late[len(late) // 2] * 1e3:.4f} ms, "

@@ -4,7 +4,7 @@
         --rates <frames/s> ...
 
 Serves the cell's open loop at each offered rate in the order given (one process, one
-engine) and prints, per rate, the latency percentiles and whether the
+server) and prints, per rate, the latency percentiles and whether the
 backlog grew: the median latency of the window's last quarter of requests
 against its first. The knee is the highest rate whose p95 stays within
 ``SLO_MS`` (one frame period of a 30 frames/s camera) with no growing
@@ -45,13 +45,9 @@ def main(argv=None) -> int:
     spec = run.load_cell(run.ROOT, args.workload)
     devs = run.require_chips(spec["cell"]["chips"])
     run.use_compile_cache(run.ROOT)
-    from bench import inputs, loops, stats, system
-    cfg, traffic = spec["config"], spec["traffic"]
-    mb = cfg["microbatch"]
-    params = inputs.weights(cfg, args.seed)
-    pool = inputs.frame_pool(cfg, run.POOL_BLOCKS * mb, args.seed)
-    eng = system.engine(cfg, params, inputs.engine_seed(args.seed))
-    server = loops.Server(eng, pool, mb)
+    from bench import loops, stats
+    traffic = spec["traffic"]
+    server = spec["kind"].Server(spec["config"], args.seed)
     knee = None
     for rate in args.rates:
         rec = loops.open_loop(server, {**traffic, "rate_per_s": rate},

@@ -1,5 +1,6 @@
 """Makes the checkout root (for ``bench``) and ``src`` (for the program)
-importable, and gives the tests a tiny copy of each configuration."""
+importable, and gives the tests a tiny copy of each configuration and the
+module of their kind."""
 import copy
 import json
 import os
@@ -41,3 +42,10 @@ def tiny_cifar():
 @pytest.fixture
 def tiny_imagenet():
     return tiny("vgg16_imagenet", hw=16)
+
+
+@pytest.fixture(scope="session")
+def p2m_vgg():
+    """The ``p2m_vgg`` kind's module, as ``run.py`` loads it."""
+    from bench import run
+    return run.load_kind(ROOT, "p2m_vgg")

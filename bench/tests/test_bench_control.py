@@ -15,24 +15,21 @@ import json
 import jax
 import pytest
 
-from bench import correct, inputs, loops, run, system
+from bench import correct, loops
 from test_bench_run import checkout, drive  # noqa: F401  (fixture)
 
 
-def test_control_is_incorrect(tiny_cifar):
+def test_control_is_incorrect(p2m_vgg, tiny_cifar):
     seed = 21
-    params = inputs.weights(tiny_cifar, seed)
-    pool = inputs.frame_pool(tiny_cifar, 8 * tiny_cifar["microbatch"], seed)
-    eng = system.engine(tiny_cifar, params, inputs.engine_seed(seed))
-    rec = loops.closed(loops.Server(eng, pool, tiny_cifar["microbatch"]),
-                       {"batch_microbatches": 2}, 0.5, seed, 6)
-    lim = correct.limits(tiny_cifar)
-    ok, _ = correct.judge(correct.readings(tiny_cifar, params, pool, seed,
+    server = p2m_vgg.Server(tiny_cifar, seed)
+    rec = loops.closed(server, {"batch_microbatches": 2}, 0.5, seed, 6)
+    lim = p2m_vgg.limits(tiny_cifar)
+    ok, _ = correct.judge(p2m_vgg.readings(tiny_cifar, server, seed,
                                            rec.samples), lim)
     assert ok
-    ok, rows = correct.judge(correct.readings(tiny_cifar, params, pool,
-                                              seed, rec.samples,
-                                              control=True), lim)
+    ok, rows = correct.judge(p2m_vgg.readings(tiny_cifar, server, seed,
+                                              rec.samples, control=True),
+                             lim)
     assert not ok, rows
 
 

@@ -57,29 +57,32 @@ def _events():
     return {"device": device, "modules": modules, "host": host}
 
 
-def _reduce(events=None):
-    return pt.reduce(events or _events(), 2e-6, pt.scopes([HLO]))
+def _reduce(kind, events=None):
+    return pt.reduce(events or _events(), 2e-6, pt.scopes([HLO], kind.SCOPES),
+                     kind.SPANS)
 
 
-def test_scope_is_the_innermost_model_scope():
-    assert pt.scope_of("jit(f)/backbone/conv12/add") == "backbone/conv12"
-    assert pt.scope_of("jit(f)/backbone/s1b0/c1") == "backbone/s1b0"
-    assert pt.scope_of("jit(f)/backbone/add") == "backbone"
-    assert pt.scope_of("jit(f)/p2m_frontend/jit(g)/p2m_kernel_a/while/"
-                       "body/add") == "p2m_frontend"
-    assert pt.scope_of("jit(f)/headless/mul") == ""
+def test_scope_is_the_innermost_model_scope(p2m_vgg):
+    def scope_of(op_name):
+        return pt.scope_of(op_name, p2m_vgg.SCOPES)
+    assert scope_of("jit(f)/backbone/conv12/add") == "backbone/conv12"
+    assert scope_of("jit(f)/backbone/s1b0/c1") == "backbone/s1b0"
+    assert scope_of("jit(f)/backbone/add") == "backbone"
+    assert scope_of("jit(f)/p2m_frontend/jit(g)/p2m_kernel_a/while/"
+                    "body/add") == "p2m_frontend"
+    assert scope_of("jit(f)/headless/mul") == ""
 
 
-def test_hlo_text_gives_each_instruction_its_scope():
-    table = pt.scopes([HLO])
+def test_hlo_text_gives_each_instruction_its_scope(p2m_vgg):
+    table = pt.scopes([HLO], p2m_vgg.SCOPES)
     assert table["jit__forward"] == {
         "multiply.9": "p2m_frontend", "fusion.1": "p2m_frontend",
         "custom-call.2": "p2m_frontend", "convolution.3": "backbone/conv0",
         "fusion.4": "backbone/conv1", "dot.5": "head"}
 
 
-def test_device_seconds_by_scope():
-    s = _reduce()["scope_s"]
+def test_device_seconds_by_scope(p2m_vgg):
+    s = _reduce(p2m_vgg)["scope_s"]
     assert s["p2m_frontend"] == pytest.approx(150e-9)
     assert s["backbone/conv0"] == pytest.approx(300e-9)
     assert s["backbone/conv1"] == pytest.approx(100e-9)
@@ -89,14 +92,16 @@ def test_device_seconds_by_scope():
     assert s[""] == pytest.approx(20e-9)
 
 
-def test_idle_gaps_go_to_the_innermost_engine_span():
-    out = _reduce()
+def test_idle_gaps_go_to_the_innermost_engine_span(p2m_vgg):
+    out = _reduce(p2m_vgg)
     assert out["idle_s"] == {"theta_sync": pytest.approx(50e-9),
                              "merge": pytest.approx(300e-9),
                              "stream": pytest.approx(280e-9),
                              "harness": pytest.approx(290e-9)}
     assert set(out["span_s"]) == {"stream", "microbatch", "theta_sync",
                                   "merge"}
+    assert out["span_n"] == {"stream": 1, "microbatch": 1, "theta_sync": 1,
+                             "merge": 1}
     assert [g[1:] for g in out["top_gaps"]] == [
         ["harness", "np.asarray"], ["stream", "stream"],
         # an eager op dispatched inside the merge
@@ -105,15 +110,17 @@ def test_idle_gaps_go_to_the_innermost_engine_span():
     assert out["top_gaps"][0][0] == pytest.approx(290e-9)
 
 
-def test_a_program_without_scopes_or_spans_gives_empty_tables():
+def test_a_program_without_scopes_or_spans_gives_empty_tables(p2m_vgg):
     ev = _events()
     ev["host"] = [("PjitFunction(f)", 0, 2000, "")]
     out = pt.reduce(ev, 2e-6, pt.scopes([HLO.replace("backbone", "x")
                                          .replace("p2m_frontend", "y")
-                                         .replace("head", "z")]))
+                                         .replace("head", "z")],
+                                        p2m_vgg.SCOPES), p2m_vgg.SPANS)
     assert set(out["scope_s"]) == {""} and out["span_s"] == {}
     assert set(out["idle_s"]) == {"harness"}
-    assert pt.reduce({"device": {}, "host": []}, 1.0, {})["idle_s"] == {}
+    assert pt.reduce({"device": {}, "host": []}, 1.0, {},
+                     p2m_vgg.SPANS)["idle_s"] == {}
 
 
 _NEW = ("backbone_flops_share", "frontend_scope_roofline_share",
@@ -121,46 +128,50 @@ _NEW = ("backbone_flops_share", "frontend_scope_roofline_share",
         "engine.merge_idle_share", "window_compiles", "open.window_compiles")
 
 
-def _ctx(tiny_cifar, program, **rec):
-    """The readers' shared context with ``of_run``'s result in place."""
+def _ctx(kind, tiny_cifar, program, **rec):
+    """The readers' shared context with the window's reduction by program
+    stage in place."""
     rec = types.SimpleNamespace(**{"frames": 1000, "window_s": 10.0,
                                    "stream_steps": 40, "counters": {},
                                    "traced": {"busy_s": 1.0,
-                                              "window_s": 10.0},
+                                              "window_s": 10.0,
+                                              "program": program},
                                    **rec})
-    return {"rec": rec, "config": tiny_cifar,
-            "peak": run.peak_of(ROOT, "TPU v5 lite"), "chips": 1,
-            "program": program}
+    return {"rec": rec, "config": tiny_cifar, "kind": kind,
+            "peak": run.peak_of(ROOT, "TPU v5 lite"), "chips": 1}
 
 
 @pytest.mark.parametrize("name", _NEW)
-def test_reader_is_silent_without_its_input(name, tiny_cifar):
+def test_reader_is_silent_without_its_input(name, p2m_vgg, tiny_cifar):
     """Run against a program that lacks the scopes, spans and counters
     (an older one), or a window with no device, each reader returns None
     and raises nothing."""
-    older = pt.reduce({"device": {}, "host": []}, 1.0, {})
-    older.update(compiles=None, syncs=None, microbatches=3)
-    ctx = _ctx(tiny_cifar, older)
+    older = pt.reduce({"device": {}, "host": []}, 1.0, {}, p2m_vgg.SPANS)
+    older.update(marks={"compiles": None, "syncs": None},
+                 span_n={"microbatch": 3})
+    ctx = _ctx(p2m_vgg, tiny_cifar, older)
     assert run.read_metric(ROOT, name)(ctx) is None
-    assert run.read_metric(ROOT, name)(_ctx(tiny_cifar, None)) is None
+    assert run.read_metric(ROOT, name)(
+        _ctx(p2m_vgg, tiny_cifar, None)) is None
 
 
-def test_readers_compute_from_their_inputs(tiny_cifar):
-    from bench import counts
+def test_readers_compute_from_their_inputs(p2m_vgg, tiny_cifar):
+    from bench.stats import least_seconds
     program = {"scope_s": {"backbone": 0.5, "p2m_frontend": 0.25},
                "span_s": {"merge": 3.0}, "idle_s": {"merge": 2.0},
-               "window_s": 10.0, "compiles": 0, "syncs": 42,
-               "microbatches": 40}
-    ctx = _ctx(tiny_cifar, program)
+               "window_s": 10.0, "marks": {"compiles": 0, "syncs": 42},
+               "span_n": {"microbatch": 40}}
+    ctx = _ctx(p2m_vgg, tiny_cifar, program)
     peak = ctx["peak"]
 
     def read(name):
         return run.read_metric(ROOT, name)(ctx)
 
-    flops = 2 * counts.backbone_macs(tiny_cifar) * 1000
+    flops = 2 * p2m_vgg.backbone_macs(tiny_cifar) * 1000
     assert read("backbone_flops_share") == pytest.approx(
         100 * flops / peak["bf16_flops_per_s"] / 0.5)
-    least, _ = counts.frontend_min_seconds(tiny_cifar, 1000, peak)
+    least, _ = least_seconds(p2m_vgg.work(tiny_cifar)["p2m_frontend"], 1000,
+                             peak)
     assert read("frontend_scope_roofline_share") == pytest.approx(
         100 * least / 0.25)
     assert read("engine.host_syncs_per_microbatch") == pytest.approx(1.05)
@@ -170,25 +181,28 @@ def test_readers_compute_from_their_inputs(tiny_cifar):
     assert read("open.window_compiles") == 0
 
 
-def test_of_run_reads_once_and_needs_a_device(tiny_cifar, tmp_path):
-    """No device time in the window (a CPU run): nothing to reduce, and
-    the trace is never opened; the answer is kept for the next reader."""
-    reader = str(tmp_path / "bench" / "metrics" / "x.py")
-    for traced in (None, {"busy_s": 0.0, "window_s": 1.0}):
-        ctx = _ctx(tiny_cifar, None, traced=traced)
-        del ctx["program"]
-        assert pt.of_run(ctx, reader) is None and ctx["program"] is None
-    ctx = _ctx(tiny_cifar, {"kept": True})
-    assert pt.of_run(ctx, reader) == {"kept": True}
+def test_of_run_reads_once_and_needs_a_device(p2m_vgg, tiny_cifar):
+    """No device time in the window (a CPU run) or no traced window:
+    nothing was reduced by stage. Otherwise every reader gets the one
+    reduction the window's stop made."""
+    for traced in (None, {"busy_s": 0.0, "window_s": 1.0, "program": None}):
+        ctx = _ctx(p2m_vgg, tiny_cifar, None, traced=traced)
+        assert pt.of_run(ctx) is None
+    kept = {"kept": True}
+    ctx = _ctx(p2m_vgg, tiny_cifar, kept)
+    assert pt.of_run(ctx) is kept and pt.of_run(ctx) is kept
 
 
-def test_step_programs_name_every_scope(tiny_cifar):
-    """The engine rebuilt on the weights' shapes lowers the served step
-    programs (the exact step and, on pallas, the fused one), whose HLO
-    names each instruction's model scope."""
-    texts = pt.step_programs(tiny_cifar)
+def test_step_programs_name_every_scope(p2m_vgg, tiny_cifar, monkeypatch):
+    """The served engine lowers its own step programs (the exact step
+    and, on pallas, the fused one), whose HLO names each instruction's
+    model scope; no engine is built for it."""
+    server = p2m_vgg.Server(tiny_cifar, 0)
+    # building an engine from here on fails
+    monkeypatch.setattr(p2m_vgg.VisionEngine, "__init__", None)
+    texts = server.step_programs()
     assert len(texts) == 2
-    tables = pt.scopes(texts)
+    tables = pt.scopes(texts, p2m_vgg.SCOPES)
     assert set(tables) == {"jit__forward", "jit__forward_fused"}
     for table in tables.values():
         found = set(table.values())
@@ -199,10 +213,10 @@ def test_step_programs_name_every_scope(tiny_cifar):
 def _host_events(tmp_path):
     path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
                           "*.xplane.pb"))
-    return [e[0] for e in pt.load_events(path, [])["host"]]
+    return [e[0] for e in trace_reduce.load_events(path, [])["host"]]
 
 
-def test_compile_marks_land_in_the_trace(tmp_path):
+def test_compile_marks_land_in_the_trace(p2m_vgg, tmp_path):
     """Each program load while an ``Obs`` lives leaves one ``jax_compile``
     mark on the profiler's clock, read back by ``load_events``."""
     from repro.obs import Obs
@@ -218,23 +232,20 @@ def test_compile_marks_land_in_the_trace(tmp_path):
     loads = (obs.registry.snapshot()["jax_compiles_total"]["value"]
              - (before or {}).get("value", 0.0))
     assert loads >= 1
-    assert _host_events(tmp_path).count(pt.COMPILE_MARK) == loads
+    assert _host_events(tmp_path).count(p2m_vgg.MARKS["compiles"]) == loads
 
 
 @pytest.mark.parametrize("cfg_name", ["tiny_cifar", "tiny_imagenet"])
-def test_host_sync_marks_land_in_the_trace(cfg_name, request, tmp_path):
+def test_host_sync_marks_land_in_the_trace(cfg_name, p2m_vgg, request,
+                                           tmp_path):
     """A stream of two-microbatch items under the profiler, on the fused
     (pallas) and the deferred (device) path: one ``host_sync`` mark per
     increment of ``serving_host_syncs_total`` and one ``microbatch`` span
-    per microbatch, as ``of_run`` counts them."""
-    from bench import inputs, system
-    from repro.obs import Obs
+    per microbatch, as ``of_window`` counts them."""
     cfg = request.getfixturevalue(cfg_name)
-    mb = cfg["microbatch"]
-    obs = Obs()
-    eng = system.engine(cfg, inputs.weights(cfg, 3), 3, obs)
-    pool = inputs.frame_pool(cfg, 2 * mb, 3)
-    outs = eng.stream(iter([pool] * 3))
+    server = p2m_vgg.Server(cfg, 3, trace=True, pool_blocks=2)
+    obs = server.obs
+    outs = server.engine.stream(iter([server.pool] * 3))
     next(outs)                  # compiles outside the trace
     jax.profiler.start_trace(str(tmp_path))
     try:
@@ -246,12 +257,12 @@ def test_host_sync_marks_land_in_the_trace(cfg_name, request, tmp_path):
         jax.profiler.stop_trace()
     names = _host_events(tmp_path)
     assert syncs >= 2
-    assert names.count(pt.SYNC_MARK) == syncs
+    assert names.count(p2m_vgg.MARKS["syncs"]) == syncs
     assert names.count("microbatch") == 4
-    assert pt._marks() == (True, True)
+    assert p2m_vgg.MARKS == {"compiles": "jax_compile", "syncs": "host_sync"}
 
 
-def test_recorded_chip_trace():
+def test_recorded_chip_trace(p2m_vgg):
     """One stream item of a TPU v5e trace of the vgg16_imagenet.stream
     window, with the scope of each instruction read from the programs'
     HLO and the numbers both reductions gave when it was recorded."""
@@ -264,7 +275,7 @@ def test_recorded_chip_trace():
               "host": [tuple(e) for e in rec["host"]]}
     old = trace_reduce.reduce(events, rec["window_s"])
     assert old["busy_s"] == pytest.approx(rec["expect"]["busy_s"])
-    out = pt.reduce(events, rec["window_s"], rec["programs"])
+    out = pt.reduce(events, rec["window_s"], rec["programs"], p2m_vgg.SPANS)
     assert out["scope_s"] == pytest.approx(rec["expect"]["scope_s"])
     assert out["idle_s"] == pytest.approx(rec["expect"]["idle_s"])
     s = out["scope_s"]

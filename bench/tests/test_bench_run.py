@@ -4,6 +4,8 @@ The chip check is the one thing replaced (``require=``); the configuration
 files are tiny copies in a scratch checkout, everything else is the
 harness as the chip runs it: set-up, the window, the seeded sample and its
 comparison with the reference, the metric readers and the result line.
+A kind the harness has never seen (``data/toy_kind.py``) runs through it
+as new files only.
 """
 import json
 import os
@@ -14,18 +16,20 @@ import sys
 import jax
 import pytest
 
-from bench import run
+from bench import loops, run
 from conftest import ROOT, tiny
+
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
 
 @pytest.fixture(scope="module")
 def checkout(tmp_path_factory):
-    """A scratch root: BENCHMARK.json and bench/'s data files, with tiny
-    configurations, a slow open-loop rate with long windows and a peak row
-    for the CPU."""
+    """A scratch root: BENCHMARK.json, bench/'s data files and kinds, with
+    tiny configurations, a slow open-loop rate with long windows and a peak
+    row for the CPU."""
     root = tmp_path_factory.mktemp("checkout")
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    for d in ("traffic", "cells", "metrics"):
+    for d in ("traffic", "cells", "metrics", "kinds"):
         shutil.copytree(os.path.join(ROOT, "bench", d), root / "bench" / d)
     (root / "bench" / "configs").mkdir()
     for name, hw in (("vgg16_cifar10", 8), ("vgg16_imagenet", 16)):
@@ -48,6 +52,39 @@ def checkout(tmp_path_factory):
         peaks = json.load(f)
     peaks["devices"]["cpu"] = peaks["devices"]["TPU v5 lite"]
     (root / "bench" / "peaks.json").write_text(json.dumps(peaks))
+    return str(root)
+
+
+@pytest.fixture(scope="module")
+def toy_checkout(checkout, tmp_path_factory):
+    """A copy of the scratch checkout with a new kind added as new files
+    and entries only: ``bench/kinds/toy.py``, its configuration, a traffic
+    mix that asks for the kind's own loop, and two cells; plus two
+    configurations whose kind is unknown or not named."""
+    root = tmp_path_factory.mktemp("toy") / "checkout"
+    shutil.copytree(checkout, root)
+    shutil.copy(os.path.join(DATA, "toy_kind.py"),
+                root / "bench" / "kinds" / "toy.py")
+    configs = {"toy_mlp": {"kind": "toy", "name": "toy_mlp", "inputs": 24,
+                           "hidden": 32, "classes": 10, "microbatch": 8,
+                           "limits": {"logit_gap": 1e-4}},
+               "ghost": {"kind": "no_such_kind", "name": "ghost"},
+               "nameless": {"name": "nameless"}}
+    for name, cfg in configs.items():
+        (root / "bench" / "configs" / (name + ".json")).write_text(
+            json.dumps(cfg))
+    (root / "bench" / "traffic" / "toy_stream.json").write_text(json.dumps(
+        {"loop": "toy_closed", "batch_microbatches": 2}))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    for cfg, traffic in (("toy_mlp", "stream"), ("toy_mlp", "toy_stream"),
+                         ("ghost", "stream"), ("nameless", "stream")):
+        bench["workloads"].append({"name": f"{cfg}.{traffic}",
+                                   "config": cfg, "traffic": traffic,
+                                   "chips": 1, "why": "harness test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in ("frames_per_s", "step_mfu"):
+            m["workloads"] += ["toy_mlp.stream", "toy_mlp.toy_stream"]
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return str(root)
 
 
@@ -141,3 +178,84 @@ def test_command_in_a_bare_checkout_exits_nonzero(tmp_path):
                         "1", "--trace", "0"], cwd=tmp_path, env=env,
                        capture_output=True, text=True, timeout=300)
     assert p.returncode != 0 and p.stdout == ""
+
+
+@pytest.mark.parametrize("traffic", ["stream", "toy_stream"])
+def test_new_kind_runs_as_new_files(toy_checkout, traffic, capsys):
+    """A two-layer classifier with its own reference, through the shared
+    closed loop and through a loop of its own."""
+    rc, res, err = drive(toy_checkout, f"toy_mlp.{traffic}", capsys=capsys)
+    assert rc == 0 and res["correct"] is True, res["checks"]
+    assert [c["name"] for c in res["checks"]] == ["logit_gap"]
+    assert set(res["metrics"]) == {"frames_per_s", "setup_s"}
+    assert res["attempted"] > 0 and res["failed"] == 0
+    assert ("toy loop: closed" in err) == (traffic == "toy_stream")
+
+
+def test_new_kind_reads_its_own_work(toy_checkout, capsys):
+    rc, res, _ = drive(toy_checkout, "toy_mlp.stream", trace=1,
+                       capsys=capsys)
+    assert rc == 0 and res["correct"] is True
+    assert set(res["metrics"]) == {"step_mfu"}
+    assert res["metrics"]["step_mfu"]["value"] > 0
+
+
+def test_new_kind_with_a_planted_fault_is_incorrect(toy_checkout, capsys,
+                                                    monkeypatch):
+    """One served logit altered where the program produces it."""
+    toy = run.load_kind(toy_checkout, "toy")
+    forward = toy.forward
+    monkeypatch.setattr(toy, "forward",
+                        lambda p, x: forward(p, x).at[0, 0].add(0.5))
+    rc, res, _ = drive(toy_checkout, "toy_mlp.stream", capsys=capsys)
+    assert rc == 0 and res["correct"] is False
+    assert res["checks"][0]["value"] > res["checks"][0]["limit"]
+
+
+@pytest.mark.parametrize("workload,error", [
+    ("ghost.stream", "unknown kind 'no_such_kind'"),
+    ("nameless.stream", "names no kind")])
+def test_unknown_kind_exits_nonzero_and_prints_no_result(
+        toy_checkout, workload, error, capsys):
+    with pytest.raises(SystemExit) as e:
+        drive(toy_checkout, workload, capsys=capsys)
+    assert e.value.code not in (0, None) and error in str(e.value.code)
+    assert capsys.readouterr().out == ""
+
+
+class _Clock:
+    """A clock that moves ``step`` seconds at each reading, so that a
+    window serves the same items however fast the CPU is."""
+
+    def __init__(self, step: float):
+        self.t, self.step = 0.0, step
+
+    def __call__(self) -> float:
+        self.t += self.step
+        return self.t
+
+
+# each cell's requests and compared numbers on one seed at the tiny
+# sizes, with the loops on ``_Clock(0.05)``: what the harness read before
+# the kinds were taken out of it, to the last digit
+PINNED = {
+    "vgg16_cifar10.stream": (80, {
+        "rate_flips": 0.0, "theta_gap": 6.452976057246378e-07,
+        "frames_off": 0.0, "theta_guard": 0.013351517539288772}),
+    "vgg16_imagenet.stream": (80, {
+        "rate_flips": 0.0, "theta_gap": 0.0, "frames_off": 0.0,
+        "theta_guard": 0.0}),
+    "vgg16_cifar10.open": (75, {
+        "rate_flips": 0.0, "theta_gap": 5.784842613233232e-07,
+        "frames_off": 0.0, "theta_guard": 0.007896078830206961}),
+}
+
+
+@pytest.mark.parametrize("workload", sorted(PINNED))
+def test_checks_are_pinned(checkout, workload, capsys, monkeypatch):
+    monkeypatch.setattr(loops, "now", _Clock(0.05))
+    rc, res, _ = drive(checkout, workload, seed=2 ** 32 + 17,
+                       capsys=capsys)
+    assert rc == 0
+    assert (res["attempted"], {c["name"]: c["value"]
+                               for c in res["checks"]}) == PINNED[workload]

@@ -30,13 +30,13 @@ def test_reservoir_sample_is_seeded():
     assert len(sample(1)) == 4 and sample(1) != sample(2)
 
 
-def test_item_samples_one_per_microbatch():
+def test_item_samples_one_per_microbatch(p2m_vgg):
     """An item of two microbatches gives two samples: each its own frames,
     outputs and rows of the served result; padding rows are not real."""
     idx = np.arange(10, 18)
     parts = [{"theta": 1.0, "channel_rates": 0, "labels": 0},
              {"theta": 2.0, "channel_rates": 1, "labels": 1}]
-    got = loops.item_samples(5, idx, 6, {"probs": "served"}, parts)
+    got = p2m_vgg.item_samples(5, idx, 6, {"probs": "served"}, parts)
     assert [(s.index, s.part, s.parts, s.row0, s.real) for s in got] == [
         (5, 0, 2, 0, 4), (5, 1, 2, 4, 2)]
     assert list(got[1].frames) == [14, 15, 16, 17]

@@ -52,10 +52,12 @@ def test_no_device_plane_gives_no_busy_time():
     assert out["busy_s"] == 0.0 and out["kernel_s"] == {}
 
 
-def test_scope_is_found_in_event_metadata():
+def test_scope_is_found_in_event_metadata(p2m_vgg):
     stats = [("tf_op", "jit(_forward)/p2m_kernel_fused/pallas_call")]
-    assert tr._scope_of("custom-call.7", stats) == "p2m_kernel"
-    assert tr._scope_of("fusion.7", [("tf_op", "jit(_forward)/conv")]) == ""
+    kernels = p2m_vgg.KERNELS
+    assert tr._scope_of("custom-call.7", stats, kernels) == "p2m_kernel"
+    assert tr._scope_of("fusion.7", [("tf_op", "jit(_forward)/conv")],
+                        kernels) == ""
 
 
 def test_recorded_chip_trace():

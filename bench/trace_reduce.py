@@ -3,15 +3,17 @@
 ``Window`` runs ``jax.profiler`` around the measured window (python tracer
 off, host TraceMe annotations on, so the program's spans and JAX's
 dispatches land on the same clock as the device's operations).
-``load_events`` reads the ``.xplane.pb`` into plain tuples and ``reduce``
-turns those into:
+``load_events`` reads the ``.xplane.pb`` into plain tuples once; ``reduce``
+turns those into the numbers below, and ``program_trace.of_window`` the
+same events into the program's stages (``program``):
 
 busy_s      the union of the device's operation intervals ("XLA Ops"
             line of each ``/device:TPU:<n>`` plane), averaged over chips
 window_s    the traced window's length on the host clock
 kernel_s    device seconds of the operations whose name or metadata
-            carries a kernel scope (``p2m_kernel`` covers every frontend
-            kernel: A, B, fused, f32 and int8)
+            carries one of the kind's kernel names (``KERNELS``; for
+            ``p2m_vgg``, ``p2m_kernel`` covers every frontend kernel: A, B,
+            fused, f32 and int8)
 breakdown   the ten device operations that took most time, and the idle
             gaps between device operations summed by what the host's
             python thread (line ``python*``) was doing in them (the innermost annotation or
@@ -30,7 +32,6 @@ import shutil
 import time
 from typing import Dict, List, Sequence, Tuple
 
-KERNEL_SCOPES = ("p2m_kernel",)
 _DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 
 # (name, start_ns, duration_ns, scope) -- scope: the kernel scope the event
@@ -44,39 +45,49 @@ def _short(name: str) -> str:
     return name.split(" = ", 1)[0].lstrip("%")
 
 
-def _scope_of(name: str, stats) -> str:
-    for scope in KERNEL_SCOPES:
+def _scope_of(name: str, stats, kernels: Sequence[str]) -> str:
+    for scope in kernels:
         if scope in name:
             return scope
     for _, value in stats:
         if isinstance(value, str):
-            for scope in KERNEL_SCOPES:
+            for scope in kernels:
                 if scope in value:
                     return scope
     return ""
 
 
-def load_events(path: str, device_ids: Sequence[int]) -> Dict:
-    """{"device": {id: [Event]}, "host": [Event]} from an xplane file."""
+def load_events(path: str, device_ids: Sequence[int],
+                kernels: Sequence[str] = ()) -> Dict:
+    """{"device": {id: [Event]}, "modules": {id: [Event]}, "host":
+    [Event]} from an xplane file: each device's operations (its "XLA Ops"
+    line, each tagged with the kernel of ``kernels`` it belongs to) and
+    program runs (its "XLA Modules" line), and the host's python thread."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     device: Dict[int, List[Event]] = {}
+    modules: Dict[int, List[Event]] = {}
     host: List[Event] = []
     for plane in data.planes:
         m = _DEVICE_PLANE.match(plane.name)
         if m and int(m.group(1)) in device_ids:
+            i = int(m.group(1))
             for line in plane.lines:
-                if line.name != "XLA Ops":
-                    continue
-                device.setdefault(int(m.group(1)), []).extend(
-                    (_short(e.name), e.start_ns, e.duration_ns,
-                     _scope_of(e.name, e.stats)) for e in line.events)
+                if line.name == "XLA Modules":
+                    modules.setdefault(i, []).extend(
+                        (e.name, e.start_ns, e.duration_ns, "")
+                        for e in line.events)
+                elif line.name == "XLA Ops":
+                    device.setdefault(i, []).extend(
+                        (_short(e.name), e.start_ns, e.duration_ns,
+                         _scope_of(e.name, e.stats, kernels))
+                        for e in line.events)
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 if line.name.startswith("python"):
                     host.extend((e.name, e.start_ns, e.duration_ns, "")
                                 for e in line.events)
-    return {"device": device, "host": host}
+    return {"device": device, "modules": modules, "host": host}
 
 
 def _union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
@@ -136,10 +147,14 @@ def reduce(events: Dict, window_s: float, top: int = 10) -> Dict:
 
 class Window:
     """The profiler around one measured window, written under ``out_dir``
-    (emptied first) and reduced when it stops."""
+    (emptied first). When it stops, the trace is read once and reduced
+    twice: by device operation and host event (``reduce``) and, where a
+    device ran anything, by the program's stages (``program``), with the
+    kind's names and the served engine's own step programs
+    (``server.step_programs``)."""
 
-    def __init__(self, out_dir: str, devs):
-        self.out_dir = out_dir
+    def __init__(self, out_dir: str, devs, kind, server):
+        self.out_dir, self.kind, self.server = out_dir, kind, server
         self.ids = [d.id for d in devs]
 
     def start(self) -> None:
@@ -153,10 +168,16 @@ class Window:
 
     def stop(self, window_s: float) -> Dict:
         import jax
+
+        from bench import program_trace
         jax.profiler.stop_trace()
         t = time.perf_counter()
         paths = sorted(glob.glob(os.path.join(
             self.out_dir, "plugins", "profile", "*", "*.xplane.pb")))
-        out = reduce(load_events(paths[-1], self.ids), window_s)
+        events = load_events(paths[-1], self.ids, self.kind.KERNELS)
+        out = reduce(events, window_s)
+        out["program"] = (program_trace.of_window(
+            events, window_s, self.kind, self.server.step_programs())
+            if out["busy_s"] > 0 else None)
         out["reduce_s"] = time.perf_counter() - t
         return out
