@@ -1,9 +1,12 @@
-"""P2M binary-spike front-end for the multimodal archs (chameleon / whisper).
+"""P2M binary-spike front-end as a language model's vision input.
 
-The paper's technique is a *sensor front-end*; for the assigned VLM/audio
-architectures it replaces the modality tokenizer: the in-pixel layer emits
-binary spike maps, which are packed into discrete codes and embedded into the
-backbone's vocabulary — an ADC-less, 1-bit-link camera feeding an LLM.
+The paper's technique is a *sensor front-end*; for a vision-language model
+it takes the vision tower's place: the in-pixel layer emits binary spike
+maps, a projector (``models/projector.py``) turns their 14x14 patches into
+image embeddings at the first positions of the prompt, and the decoder
+answers — an ADC-less, 1-bit-link camera feeding an LLM. This runs the
+normal serving path (``ServingEngine`` with ``frontend=``) on a reduced
+Kimi-VL-A3B decoder.
 
     PYTHONPATH=src python examples/p2m_frontend.py
 """
@@ -12,56 +15,43 @@ import jax.numpy as jnp
 
 from repro import configs, frontend
 from repro.configs.reduced import reduced
-from repro.core import energy, p2m
-from repro.models import lm
+from repro.core import p2m
+from repro.models import lm, projector
+from repro.models.params import init_tree
+from repro.serving import ServingEngine
 
-
-def spikes_to_tokens(spikes: jax.Array, vocab: int, bits: int = 8
-                     ) -> jax.Array:
-    """Pack binary spike channels into discrete codes (B, H', W') -> tokens.
-
-    Groups of ``bits`` channels form one code in [0, 2^bits); codes index the
-    tail of the backbone vocabulary (early-fusion, chameleon-style).
-    """
-    b, h, w, c = spikes.shape
-    groups = c // bits
-    x = spikes[..., :groups * bits].reshape(b, h, w, groups, bits)
-    weights = 2 ** jnp.arange(bits)
-    codes = jnp.sum(x.astype(jnp.int32) * weights, axis=-1)   # (B,H',W',G)
-    toks = (vocab - 2 ** bits) + codes
-    return toks.reshape(b, -1)
+PATCH = projector.PATCH
 
 
 def main() -> None:
-    cfg = reduced(configs.get_arch("chameleon-34b"))
-    print("backbone:", cfg.name, "(reduced)")
+    cfg = reduced(configs.get_arch("kimi-vl-a3b"))
+    print("decoder:", cfg.name, "(reduced)")
+    fcfg = frontend.FrontendConfig(p2m=p2m.P2MConfig(out_channels=32),
+                                   backend="device")
+    params = lm.init_params(jax.random.PRNGKey(0), cfg)
+    params["p2m"] = p2m.init_params(jax.random.PRNGKey(1), fcfg.p2m)
+    params["projector"] = init_tree(
+        jax.random.PRNGKey(2), projector.spec(PATCH * PATCH * 32, 128,
+                                              cfg.d_model), cfg.pdtype)
 
-    # the camera: SensorFrontend (Monte-Carlo device backend) on a frame
-    fe = frontend.SensorFrontend(frontend.FrontendConfig(
-        p2m=p2m.P2MConfig(out_channels=32), backend="device"))
-    pparams = fe.init(jax.random.PRNGKey(0))
-    frame = jax.random.uniform(jax.random.PRNGKey(1), (2, 32, 32, 3))
-    spikes, aux = fe(pparams, frame, key=jax.random.PRNGKey(2))
-    print(f"spikes: {spikes.shape}, sparsity "
-          f"{float(aux['sparsity']) * 100:.1f}%, "
-          f"V_CONV mean {float(aux['v_conv_mean']):.3f} V")
+    # the camera: two 56x56 frames -> 28x28x32 spike maps -> 4 image tokens
+    frames = jax.random.uniform(jax.random.PRNGKey(3), (2, 56, 56, 3))
+    images = (56 // fcfg.p2m.stride // PATCH) ** 2
+    text = jax.random.randint(jax.random.PRNGKey(4), (2, 12), 0,
+                              cfg.vocab_size)
+    prompts = jnp.concatenate([jnp.zeros((2, images), jnp.int32), text], 1)
 
-    tokens = spikes_to_tokens(spikes, cfg.vocab_size)
-    print(f"image tokens: {tokens.shape} in [{int(tokens.min())}, "
-          f"{int(tokens.max())}]")
-
-    # early fusion: image tokens + text prompt through the backbone
-    text = jax.random.randint(jax.random.PRNGKey(3), (2, 16), 0,
-                              cfg.vocab_size - 2 ** 8)
-    seq = jnp.concatenate([tokens[:, :48], text], axis=1)
-    params = lm.init_params(jax.random.PRNGKey(4), cfg)
-    logits, _ = lm.forward(params, seq, cfg)
-    print(f"backbone logits: {logits.shape}, finite: "
-          f"{bool(jnp.all(jnp.isfinite(logits)))}")
+    engine = ServingEngine(cfg, params, max_len=prompts.shape[1] + 8,
+                           frontend=fcfg, seed=5)
+    out = engine.serve(prompts, 8, frames=frames)
+    fe = out["frontend"][0]
+    print(f"spike rate {float(jnp.mean(fe['channel_rates'])) * 100:.1f}%, "
+          f"{images} image tokens + {text.shape[1]} text tokens per request")
+    print("generated:", out["tokens"].tolist())
 
     # the link the paper optimizes: sensor -> backbone traffic
-    raw_bits = frame.size * 12
-    spike_bits = spikes.size * 1
+    raw_bits = frames.size * 12
+    spike_bits = frames.shape[0] * (56 // 2) ** 2 * 32
     print(f"sensor link: {raw_bits} bits raw vs {spike_bits} bits binary "
           f"spikes ({raw_bits / spike_bits:.1f}x reduction before sparse "
           f"coding)")

@@ -14,6 +14,7 @@ from repro.configs.stablelm_3b import CONFIG as _stablelm
 from repro.configs.glm4_9b import CONFIG as _glm4
 from repro.configs.deepseek_v2_236b import CONFIG as _deepseek
 from repro.configs.kimi_k2_1t import CONFIG as _kimi
+from repro.configs.kimi_vl_a3b import CONFIG as _kimi_vl
 from repro.configs.xlstm_350m import CONFIG as _xlstm
 from repro.configs.whisper_base import CONFIG as _whisper
 from repro.configs.recurrentgemma_2b import CONFIG as _rgemma
@@ -21,7 +22,7 @@ from repro.configs.recurrentgemma_2b import CONFIG as _rgemma
 ARCHS: Dict[str, ArchConfig] = {
     c.name: c for c in (
         _chameleon, _granite, _yi, _stablelm, _glm4,
-        _deepseek, _kimi, _xlstm, _whisper, _rgemma,
+        _deepseek, _kimi, _kimi_vl, _xlstm, _whisper, _rgemma,
     )
 }
 

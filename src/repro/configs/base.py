@@ -40,6 +40,13 @@ class ArchConfig:
     dense_d_ff: int = 0              # d_ff of those dense layers (0 -> d_ff)
     capacity_factor: float = 1.25
     router_noise: float = 0.0
+    # "softmax": softmax over the top-k router logits, tokens past an
+    # expert's capacity dropped; "sigmoid": f32 sigmoid scores, top-k picked
+    # on scores + a per-expert selection bias (DeepSeek-V3 ``noaux_tc``),
+    # weighted by the chosen scores normalised to sum 1 times
+    # ``routed_scaling``, every assignment computed (dropless)
+    moe_routing: str = "softmax"
+    routed_scaling: float = 1.0
     # encoder-decoder (whisper)
     encoder_layers: int = 0          # > 0 => enc-dec; num_layers = decoder depth
     encoder_seq: int = 1500          # stub frame count for the audio frontend
@@ -48,6 +55,7 @@ class ArchConfig:
     rope_theta: float = 10000.0
     mlp_gated: bool = True           # SwiGLU; False -> plain GELU (whisper)
     tie_embeddings: bool = False
+    scale_embeddings: bool = True    # token embeddings x sqrt(d_model)
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
     # P2M front-end (the paper's technique) applicability

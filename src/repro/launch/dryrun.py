@@ -23,6 +23,7 @@ Usage:
 """
 import argparse
 import dataclasses
+import functools
 import json
 import traceback
 from typing import Any, Dict, Optional
@@ -111,18 +112,25 @@ def build_cell(cfg: ArchConfig, shape: ShapeSpec, mesh,
         return fn, (params, opt, ins)
 
     if shape.kind == "prefill":
-        pf = make_prefill_step(cfg, mesh, rules)
-        args = [params, ins["tokens"]]
-        if cfg.is_encdec:
-            args.append(ins["encoder_embeddings"])
-        return jax.jit(pf), tuple(args)
+        # the served prefill, growing the cache by the one token it picks
+        pf = make_prefill_step(cfg, mesh, rules, max_len=shape.seq_len + 1)
+        enc = ins["encoder_embeddings"] if cfg.is_encdec else None
+        return (jax.jit(functools.partial(pf, new_tokens=1)),
+                (params, ins["tokens"], None, enc, None))
 
-    # decode: one token against a seq_len-deep cache
+    # decode: one token against a seq_len-deep cache, in the served decode
+    # state (the cache, a buffer holding the token in and the token out, the
+    # step)
     dec = make_decode_step(cfg, mesh, rules)
     cache = abstract_cache_sharded(cfg, shape.global_batch, shape.seq_len,
                                    mesh, rules)
+    tok = ins["tokens"]
+    state = {"cache": cache,
+             "tokens": jax.ShapeDtypeStruct((tok.shape[0], 2), tok.dtype,
+                                            sharding=tok.sharding),
+             "step": jax.ShapeDtypeStruct((), jnp.int32)}
     fn = jax.jit(dec, donate_argnums=(1,))
-    return fn, (params, cache, ins["tokens"])
+    return fn, (params, state)
 
 
 def _with_layers(cfg: ArchConfig, periods: int) -> ArchConfig:

@@ -380,6 +380,13 @@ def moe_spec(cfg: ArchConfig) -> Dict[str, Any]:
         "w2": ParamSpec((e, f, d), ("expert", "expert_ffn", "embed")),
         "w3": ParamSpec((e, d, f), ("expert", "embed", "expert_ffn")),
     }
+    if cfg.moe_routing == "sigmoid":
+        # the scores decide which experts run: router and selection bias
+        # are kept, and applied, in f32
+        spec["router"] = ParamSpec((d, e), ("embed", "expert"),
+                                   dtype="float32")
+        spec["bias"] = ParamSpec((e,), ("expert",), init="zeros",
+                                 dtype="float32")
     if cfg.num_shared_experts > 0:
         fs = cfg.d_ff * cfg.num_shared_experts
         spec["shared"] = mlp_spec(cfg, d_ff=fs)
@@ -391,6 +398,60 @@ def _expert_ffn(w1, w2, w3, x):
     h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", x, w1)) * jnp.einsum(
         "ecd,edf->ecf", x, w3)
     return jnp.einsum("ecf,efd->ecd", h, w2)
+
+
+def route_sigmoid(logits: jax.Array, bias: jax.Array, top_k: int,
+                  scale: float) -> Tuple[jax.Array, jax.Array]:
+    """(weights (T, K) f32, experts (T, K)) of sigmoid routing with a
+    selection bias (DeepSeek-V3 ``noaux_tc``, one group): the top-k experts
+    by score + bias, weighted by their unbiased scores normalised to sum 1,
+    times ``scale``. logits: (T, E) f32."""
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + bias, top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    return w / jnp.sum(w, axis=-1, keepdims=True) * scale, idx
+
+
+# up to this many tokens every held expert runs on every token: that costs
+# no more time than reading the experts' weights, which any dispatch must
+# (a TPU v5e does 240 bf16 FLOPs per byte of HBM), with no sort or gather
+DENSE_TOKENS = 256
+
+
+def _moe_dropless(x_flat, weights, idx, w1, w2, w3, *, e_start: int,
+                  e_local: int):
+    """Every token-expert assignment computed (no capacity), by the experts
+    [e_start, e_start + e_local) held here, combined with ``weights`` in
+    f32; assignments to experts held elsewhere add nothing. Up to
+    ``DENSE_TOKENS`` tokens each held expert runs on every token, weighted
+    by zero where not chosen; past that the assignments are sorted by
+    expert and each projection is one grouped matmul.
+
+    x_flat: (T, D); weights, idx: (T, K). Returns the partial output (T, D).
+    """
+    t, d = x_flat.shape
+    k = idx.shape[1]
+    local = idx.reshape(-1) - e_start
+    held = (local >= 0) & (local < e_local)
+    if t <= DENSE_TOKENS:
+        comb = jnp.zeros((t, e_local), jnp.float32).at[
+            jnp.repeat(jnp.arange(t), k), jnp.clip(local, 0, e_local - 1)
+        ].add(jnp.where(held, weights.reshape(-1), 0.0))
+        h = (jax.nn.silu(jnp.einsum("td,edf->etf", x_flat, w1))
+             * jnp.einsum("td,edf->etf", x_flat, w3))
+        y = jnp.einsum("etf,efd->etd", h, w2).astype(jnp.float32)
+        return jnp.sum(y * comb.T[:, :, None], axis=0).astype(x_flat.dtype)
+    local = jnp.where(held, local, e_local)
+    order = jnp.argsort(local)
+    sizes = jnp.sum(jax.nn.one_hot(local, e_local, dtype=jnp.int32), axis=0)
+    xs = jnp.take(x_flat, order // k, axis=0)
+    h = (jax.nn.silu(jax.lax.ragged_dot(xs, w1, sizes))
+         * jax.lax.ragged_dot(xs, w3, sizes))
+    ys = jax.lax.ragged_dot(h, w2, sizes)
+    # rows past the held groups are not written by the grouped matmul
+    ys = jnp.where(held[order][:, None], ys, 0).astype(jnp.float32)
+    y = jnp.take(ys, jnp.argsort(order), axis=0).reshape(t, k, d)
+    return jnp.sum(y * weights[..., None], axis=1).astype(x_flat.dtype)
 
 
 def _moe_local(x_flat, router_logits, w1, w2, w3, *, e_start: int, e_local: int,
@@ -450,8 +511,36 @@ def _fsdp_axes(mesh: Mesh, rules, d_ff: int) -> Tuple[str, ...]:
     return ax
 
 
+def _routed(cfg: ArchConfig, x_flat, router, bias, w1, w2, w3, *,
+            e_start, e_local: int):
+    """Route ``x_flat`` (T, D) over all experts and compute the part of the
+    result that experts [e_start, e_start + e_local) give. Returns
+    (partial output (T, D), chosen experts (T, K))."""
+    e, k = cfg.num_experts, cfg.top_k
+    dt = x_flat.dtype
+    with jax.named_scope("router"):
+        if cfg.moe_routing == "sigmoid":
+            logits = jnp.dot(x_flat.astype(jnp.float32), router,
+                             precision=jax.lax.Precision.HIGHEST)
+            weights, idx = route_sigmoid(logits, bias, k, cfg.routed_scaling)
+        else:
+            logits = x_flat @ router.astype(dt)
+    with jax.named_scope("experts"):
+        if cfg.moe_routing == "sigmoid":
+            out = _moe_dropless(x_flat, weights, idx, w1.astype(dt),
+                                w2.astype(dt), w3.astype(dt),
+                                e_start=e_start, e_local=e_local)
+        else:
+            cap = int(math.ceil(x_flat.shape[0] * k / e * cfg.capacity_factor))
+            out = _moe_local(x_flat, logits, w1.astype(dt), w2.astype(dt),
+                             w3.astype(dt), e_start=e_start, e_local=e_local,
+                             top_k=k, capacity=cap)
+            idx = jax.lax.top_k(logits, k)[1]
+    return out, idx
+
+
 def moe_apply(params: Dict, x: jax.Array, cfg: ArchConfig, mesh: Optional[Mesh],
-              rules) -> jax.Array:
+              rules, return_routes: bool = False):
     """Expert-parallel MoE.
 
     Tokens are replicated over the "model" axis (they are already sharded over
@@ -463,27 +552,32 @@ def moe_apply(params: Dict, x: jax.Array, cfg: ArchConfig, mesh: Optional[Mesh],
     rest (rules["expert_ffn"] -> ("pod","data")) and all-gathered per layer —
     required to fit 236B/1T-param MoEs in 16 GB/chip; the gather is the
     transpose-friendly FSDP pattern (its cotangent is the grad reduce-scatter).
+
+    ``cfg.moe_routing`` picks the routing: "softmax" (capacity drops) or
+    "sigmoid" (selection bias, dropless). With ``return_routes`` the chosen
+    experts (B, S, K) come back beside the output on the single-shard path
+    (None on the expert-parallel one).
     """
     b, s, d = x.shape
-    e, k = cfg.num_experts, cfg.top_k
+    e = cfg.num_experts
+    bias = params.get("bias")
+    idx = None
 
     if mesh is None or "model" not in mesh.shape or mesh.shape["model"] == 1 \
             or e % max(mesh.shape.get("model", 1), 1) != 0:
         # reference path (single shard)
-        x_flat = x.reshape(-1, d)
-        logits = x_flat @ params["router"].astype(x.dtype)
-        cap = int(math.ceil(x_flat.shape[0] * k / e * cfg.capacity_factor))
-        out = _moe_local(x_flat, logits, params["w1"].astype(x.dtype),
-                         params["w2"].astype(x.dtype), params["w3"].astype(x.dtype),
-                         e_start=0, e_local=e, top_k=k, capacity=cap)
+        out, idx = _routed(cfg, x.reshape(-1, d), params["router"], bias,
+                           params["w1"], params["w2"], params["w3"],
+                           e_start=0, e_local=e)
         y = out.reshape(b, s, d)
+        idx = idx.reshape(b, s, -1)
     else:
         n_model = mesh.shape["model"]
         e_local = e // n_model
         batch_ax = sharding.batch_axes(mesh)
         fsdp = _fsdp_axes(mesh, rules, cfg.d_ff)
 
-        def shard_fn(xs, router, w1, w2, w3):
+        def shard_fn(xs, router, bias_, w1, w2, w3):
             ridx = jax.lax.axis_index("model")
             if fsdp:
                 n_fsdp = 1
@@ -498,14 +592,9 @@ def moe_apply(params: Dict, x: jax.Array, cfg: ArchConfig, mesh: Optional[Mesh],
                     # psum spans model (EP combine) + fsdp (F partial sums).
                     x_all = jax.lax.all_gather(xs, fsdp, axis=0, tiled=True)
                     t_all = x_all.shape[0] * x_all.shape[1]
-                    x_flat = x_all.reshape(t_all, d)
-                    logits = x_flat @ router.astype(xs.dtype)
-                    cap = int(math.ceil(t_all * k / e * cfg.capacity_factor))
-                    out = _moe_local(
-                        x_flat, logits, w1.astype(xs.dtype),
-                        w2.astype(xs.dtype), w3.astype(xs.dtype),
-                        e_start=ridx * e_local, e_local=e_local,
-                        top_k=k, capacity=cap)
+                    out, _ = _routed(cfg, x_all.reshape(t_all, d), router,
+                                     bias_, w1, w2, w3,
+                                     e_start=ridx * e_local, e_local=e_local)
                     out = jax.lax.psum(out, ("model",) + fsdp)
                     out = out.reshape(x_all.shape)
                     fidx = jax.lax.axis_index(fsdp)
@@ -517,13 +606,8 @@ def moe_apply(params: Dict, x: jax.Array, cfg: ArchConfig, mesh: Optional[Mesh],
                 w3 = jax.lax.all_gather(w3, fsdp, axis=2, tiled=True)
                 w2 = jax.lax.all_gather(w2, fsdp, axis=1, tiled=True)
             t_loc = xs.shape[0] * xs.shape[1]
-            x_flat = xs.reshape(t_loc, d)
-            logits = x_flat @ router.astype(xs.dtype)
-            cap = int(math.ceil(t_loc * k / e * cfg.capacity_factor))
-            out = _moe_local(x_flat, logits, w1.astype(xs.dtype),
-                             w2.astype(xs.dtype), w3.astype(xs.dtype),
-                             e_start=ridx * e_local, e_local=e_local,
-                             top_k=k, capacity=cap)
+            out, _ = _routed(cfg, xs.reshape(t_loc, d), router, bias_, w1, w2,
+                             w3, e_start=ridx * e_local, e_local=e_local)
             out = jax.lax.psum(out, "model")
             return out.reshape(xs.shape)
 
@@ -532,12 +616,14 @@ def moe_apply(params: Dict, x: jax.Array, cfg: ArchConfig, mesh: Optional[Mesh],
         y = jax.shard_map(
             shard_fn,
             mesh=mesh,
-            in_specs=(P(batch_ax, None, None), P(None, None),
+            in_specs=(P(batch_ax, None, None), P(None, None), P(None),
                       wspec1, wspec2, wspec1),
             out_specs=P(batch_ax, None, None),
             check_vma=False,
-        )(x, params["router"], params["w1"], params["w2"], params["w3"])
+        )(x, params["router"], bias if bias is not None else jnp.zeros((e,)),
+          params["w1"], params["w2"], params["w3"])
 
     if "shared" in params:
-        y = y + mlp_apply(params["shared"], x, cfg, mesh, rules)
-    return y
+        with jax.named_scope("shared"):
+            y = y + mlp_apply(params["shared"], x, cfg, mesh, rules)
+    return (y, idx) if return_routes else y

@@ -6,10 +6,20 @@ none) come from ``ArchConfig.layer_kinds()``. Homogeneous runs of layers are
 ``lax.scan``-ned over stacked parameters so the HLO stays compact at any depth
 (61-layer / 1T-param Kimi-K2 compiles as one layer body + scan).
 
-Modes: "train" (logits), "prefill" (logits + cache), "decode" (one token).
+Modes: "train" (logits), "prefill" (last position's logits + cache), "decode"
+(one token).
+
+Device scopes (``jax.named_scope``, trace-time metadata that adds no
+operation): each decoder layer's ops sit under ``lm/<mixer>`` (``lm/mla``,
+``lm/attn``, ...), ``lm/moe/{router,experts,shared}`` or ``lm/dense_mlp``,
+the token embeddings under ``lm_embed``, the final norm and head under
+``lm_head``; in decode mode all of them under
+``decode/`` as well, so a trace tells a decode step's device time from a
+prefill's.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Dict, List, Optional, Tuple
@@ -191,33 +201,51 @@ def cache_axes(cfg: ArchConfig, batch: int, max_len: int):
 # forward
 # ----------------------------------------------------------------------------
 
+# key of a MoE layer's chosen experts in its per-step cache output, taken out
+# by ``forward`` before the cache is returned
+ROUTE = "route"
+
+
+def _phase(mode: str):
+    """The decode-phase scope around a decode step's layers and head."""
+    return (jax.named_scope("decode") if mode == "decode"
+            else contextlib.nullcontext())
+
+
+def _mixer(lp: Dict, h: jax.Array, positions: jax.Array, cfg: ArchConfig,
+           mesh, rules, mixer: str, *, mode: str, cache: Optional[Dict]
+           ) -> Tuple[jax.Array, Optional[Dict]]:
+    if mixer in ("attn", "enc_attn", "local_attn"):
+        return blocks.attn_apply(
+            lp, h, positions, cfg, mesh, rules,
+            causal=(mixer != "enc_attn"),
+            window=cfg.window if mixer == "local_attn" else 0,
+            mode=mode, cache=cache)
+    if mixer == "mla":
+        return blocks.mla_apply(lp, h, positions, cfg, mesh, rules,
+                                mode=mode, cache=cache)
+    if mixer == "rglru":
+        return recurrent.rglru_apply(lp, h, cfg, mesh, rules, mode=mode,
+                                     cache=cache)
+    if mixer == "mlstm":
+        return recurrent.mlstm_apply(lp, h, cfg, mesh, rules, mode=mode,
+                                     cache=cache)
+    if mixer == "slstm":
+        return recurrent.slstm_apply(lp, h, cfg, mesh, rules, mode=mode,
+                                     cache=cache)
+    raise ValueError(mixer)
+
+
 def _apply_layer(lp: Dict, x: jax.Array, positions: jax.Array,
                  cfg: ArchConfig, mesh, rules, mixer: str, mlp: str, *,
                  mode: str, cache: Optional[Dict], enc_out: Optional[jax.Array]
                  ) -> Tuple[jax.Array, Optional[Dict]]:
     new_cache: Dict[str, Any] = {}
-    h = blocks.rmsnorm(lp["ln1"], x, cfg.norm_eps)
-    mc = cache.get("mixer") if cache else None
-    if mixer in ("attn", "enc_attn", "local_attn"):
-        out, nm = blocks.attn_apply(
-            lp["mixer"], h, positions, cfg, mesh, rules,
-            causal=(mixer != "enc_attn"),
-            window=cfg.window if mixer == "local_attn" else 0,
-            mode=mode, cache=mc)
-    elif mixer == "mla":
-        out, nm = blocks.mla_apply(lp["mixer"], h, positions, cfg, mesh, rules,
-                                   mode=mode, cache=mc)
-    elif mixer == "rglru":
-        out, nm = recurrent.rglru_apply(lp["mixer"], h, cfg, mesh, rules,
-                                        mode=mode, cache=mc)
-    elif mixer == "mlstm":
-        out, nm = recurrent.mlstm_apply(lp["mixer"], h, cfg, mesh, rules,
-                                        mode=mode, cache=mc)
-    elif mixer == "slstm":
-        out, nm = recurrent.slstm_apply(lp["mixer"], h, cfg, mesh, rules,
-                                        mode=mode, cache=mc)
-    else:
-        raise ValueError(mixer)
+    with jax.named_scope(mixer):
+        h = blocks.rmsnorm(lp["ln1"], x, cfg.norm_eps)
+        out, nm = _mixer(lp["mixer"], h, positions, cfg, mesh, rules, mixer,
+                         mode=mode,
+                         cache=cache.get("mixer") if cache else None)
     if nm is not None:
         new_cache["mixer"] = nm
     x = x + out
@@ -242,11 +270,18 @@ def _apply_layer(lp: Dict, x: jax.Array, positions: jax.Array,
                 else cache["enc_k"], ev if enc_out is not None else cache["enc_v"]
 
     if mlp != "none" and "mlp" in lp:
-        h2 = blocks.rmsnorm(lp["ln2"], x, cfg.norm_eps)
         if mlp == "moe":
-            x = x + blocks.moe_apply(lp["mlp"], h2, cfg, mesh, rules)
+            with jax.named_scope("moe"):
+                h2 = blocks.rmsnorm(lp["ln2"], x, cfg.norm_eps)
+                y, route = blocks.moe_apply(lp["mlp"], h2, cfg, mesh, rules,
+                                            return_routes=True)
+            if route is not None and mode in ("prefill", "decode"):
+                new_cache[ROUTE] = route
+            x = x + y
         else:
-            x = x + blocks.mlp_apply(lp["mlp"], h2, cfg, mesh, rules)
+            with jax.named_scope("dense_mlp"):
+                h2 = blocks.rmsnorm(lp["ln2"], x, cfg.norm_eps)
+                x = x + blocks.mlp_apply(lp["mlp"], h2, cfg, mesh, rules)
     return x, (new_cache if new_cache else None)
 
 
@@ -256,8 +291,10 @@ def _apply_unit(up: Dict, x, positions, cfg, mesh, rules, seg: Segment, *,
     new_cache = {}
     for j, (mx, mlp) in enumerate(seg.kinds):
         lc = cache.get(f"l{j}") if cache else None
-        x, nc = _apply_layer(up[f"l{j}"], x, positions, cfg, mesh, rules,
-                             mx, mlp, mode=mode, cache=lc, enc_out=enc_out)
+        with _phase(mode), jax.named_scope("lm"):
+            x, nc = _apply_layer(up[f"l{j}"], x, positions, cfg, mesh, rules,
+                                 mx, mlp, mode=mode, cache=lc,
+                                 enc_out=enc_out)
         if nc is not None:
             new_cache[f"l{j}"] = nc
     return x, (new_cache if new_cache else None)
@@ -328,19 +365,43 @@ def _run_encoder(params, emb: jax.Array, cfg: ArchConfig, mesh, rules):
     return blocks.rmsnorm(params["encoder"]["norm"], x, cfg.norm_eps)
 
 
-def forward(
+def _pop_routes(cfg: ArchConfig, decoder_cache: Dict) -> Optional[jax.Array]:
+    """Take each MoE layer's chosen experts out of a step's decoder cache:
+    (MoE layers, B, S, K), by segment and, within a scanned unit, by its
+    layer then its repeat (layer order where the unit is one layer); None
+    where no layer routed."""
+    routes = []
+    for seg in segment_plan(cfg):
+        unit = decoder_cache.get(seg.name) or {}
+        for j in range(len(seg.kinds)):
+            layer = unit.get(f"l{j}")
+            if isinstance(layer, dict) and ROUTE in layer:
+                r = layer.pop(ROUTE)
+                routes.extend(list(r) if seg.repeats > 1 else [r])
+    return jnp.stack(routes) if routes else None
+
+
+def forward_with_routes(
     params: Dict, tokens: jax.Array, cfg: ArchConfig,
     mesh=None, rules=None, *,
     mode: str = "train",
     cache: Optional[Dict] = None,
     encoder_embeddings: Optional[jax.Array] = None,
     positions: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Optional[Dict]]:
-    """tokens: (B, S) int32. Returns (logits, new_cache | None)."""
+    image_embeddings: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Optional[Dict], Optional[jax.Array]]:
+    """``forward``, with each MoE layer's chosen experts (MoE layers, B, S,
+    K) in prefill and decode on the single-shard path (else None)."""
     rules = rules or sharding.ShardingRules.make(dict(cfg.rule_overrides))
     emb = params["embed"]["w"]
-    x = jnp.take(emb, tokens, axis=0, mode="clip").astype(cfg.dtype)
-    x = x * (cfg.d_model ** 0.5)
+    with _phase(mode), jax.named_scope("lm_embed"):
+        x = jnp.take(emb, tokens, axis=0, mode="clip").astype(cfg.dtype)
+        if cfg.scale_embeddings:
+            x = x * (cfg.d_model ** 0.5)
+        if image_embeddings is not None:
+            n = image_embeddings.shape[1]
+            x = jnp.concatenate([image_embeddings.astype(x.dtype), x[:, n:]],
+                                1)
     if mesh is not None:
         x = sharding.constrain(x, ("batch", "seq", "embed"), mesh, rules)
 
@@ -358,22 +419,46 @@ def forward(
 
     x, new_cache = _run_decoder(params, x, positions, cfg, mesh, rules,
                                 mode=mode, cache=cache, enc_out=enc_out)
-    x = blocks.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, emb.astype(x.dtype))
-    else:
-        logits = x @ params["lm_head"]["w"].astype(x.dtype)
+    routes = _pop_routes(cfg, new_cache)
+    if mode == "prefill":
+        x = x[:, -1:]            # the head runs at the last position only
+    with _phase(mode), jax.named_scope("lm_head"):
+        x = blocks.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bsd,vd->bsv", x, emb.astype(x.dtype))
+        else:
+            logits = x @ params["lm_head"]["w"].astype(x.dtype)
     if mesh is not None:
         logits = sharding.constrain(logits, ("batch", "seq", "vocab"),
                                     mesh, rules)
     if mode in ("prefill", "decode"):
-        out_cache = dict(new_cache)
         prev = cache["pos"] if (cache is not None and "pos" in cache) \
             else jnp.asarray(0, jnp.int32)
         out_cache = {"decoder": new_cache,
                      "pos": prev + tokens.shape[1]}
-        return logits, out_cache
-    return logits, None
+        return logits, out_cache, routes
+    return logits, None, routes
+
+
+def forward(
+    params: Dict, tokens: jax.Array, cfg: ArchConfig,
+    mesh=None, rules=None, *,
+    mode: str = "train",
+    cache: Optional[Dict] = None,
+    encoder_embeddings: Optional[jax.Array] = None,
+    positions: Optional[jax.Array] = None,
+    image_embeddings: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Optional[Dict]]:
+    """tokens: (B, S) int32. Returns (logits, new_cache | None).
+
+    ``image_embeddings`` (B, N, d_model) take the place of the first N
+    positions' token embeddings. Prefill returns the last position's
+    logits only, (B, 1, V)."""
+    logits, out_cache, _ = forward_with_routes(
+        params, tokens, cfg, mesh, rules, mode=mode, cache=cache,
+        encoder_embeddings=encoder_embeddings, positions=positions,
+        image_embeddings=image_embeddings)
+    return logits, out_cache
 
 
 def lm_loss(params, batch: Dict, cfg: ArchConfig, mesh=None, rules=None

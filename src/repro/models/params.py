@@ -8,6 +8,7 @@ Every module declares a tree of ``ParamSpec`` leaves. From it we derive:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -35,6 +36,15 @@ def _fan_in(shape: Tuple[int, ...]) -> int:
     return math.prod(shape[:-1]) if len(shape) > 1 else shape[0] or 1
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _normal(key: jax.Array, shape: Tuple[int, ...], std: float, dtype):
+    """N(0, std^2) drawn in f32 and cast to a narrower ``dtype``, as one
+    program: the f32 draw is fused into the cast and never held whole, so a
+    leaf costs its own bytes only (a stacked bf16 expert leaf would
+    otherwise need twice that again in f32 beside it)."""
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
 def init_tree(key: jax.Array, spec_tree, dtype) -> dict:
     leaves, treedef = jax.tree.flatten(spec_tree, is_leaf=is_spec)
     keys = jax.random.split(key, len(leaves))
@@ -46,7 +56,9 @@ def init_tree(key: jax.Array, spec_tree, dtype) -> dict:
         if s.init == "ones":
             return jnp.ones(s.shape, dt)
         std = s.scale / (_fan_in(s.shape) ** 0.5)
-        return (jax.random.normal(k, s.shape, jnp.float32) * std).astype(dt)
+        if dt == jnp.float32:
+            return jax.random.normal(k, s.shape, jnp.float32) * std
+        return _normal(k, tuple(s.shape), std, dt)
 
     return jax.tree.unflatten(treedef, [one(k, s) for k, s in zip(keys, leaves)])
 

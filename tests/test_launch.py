@@ -129,6 +129,18 @@ class TestMiniDryRun:
             compiled = fn.lower(*args).compile()
             assert compiled.cost_analysis().get("flops", 0) > 0
 
+    def test_prefill_cell_compiles(self):
+        """The dry-run lowers the engine's own prefill (cache grown by the
+        token it picks)."""
+        from repro.launch.dryrun import build_cell
+        cfg = reduced(configs.get_arch("glm4-9b"))
+        mesh = make_host_mesh()
+        shape = ShapeSpec("p", 64, max(2, len(jax.devices())), "prefill")
+        with mesh:
+            fn, args = build_cell(cfg, shape, mesh)
+            compiled = fn.lower(*args).compile()
+            assert compiled.cost_analysis().get("flops", 0) > 0
+
     def test_decode_cell_compiles(self):
         from repro.launch.dryrun import build_cell
         cfg = reduced(configs.get_arch("glm4-9b"))

@@ -16,6 +16,7 @@ description at import time would make parallel test workers collect
 different tests.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -178,3 +179,77 @@ def test_fleet_step_vgg16(one_chip, vgg16, monkeypatch):
                             _sds(one_chip, (g, 16, HW, HW, CIN)),
                             _key(one_chip, g)).compile().as_text()
     assert "tpu_custom_call" in hlo
+
+
+def _on_chip(sharding, tree):
+    return jax.tree.map(lambda a: _sds(sharding, a.shape, a.dtype), tree)
+
+
+@pytest.mark.parametrize("positions", [1, 128])
+def test_sigmoid_moe_layer_kimi_vl(one_chip, positions):
+    """Kimi-VL-A3B's MoE layer at published widths for 64 requests, as a
+    decode step (64 tokens: every expert on every token, plain dots) and as
+    a prefill (8,192 tokens: sorted assignments, the grouped matmul as a
+    TPU kernel); sigmoid routing over 64 experts, top-6."""
+    import dataclasses
+
+    from repro import configs
+    from repro.models import blocks, params as params_mod
+    cfg = dataclasses.replace(configs.get_arch("kimi-vl-a3b"), num_layers=2)
+    params = _on_chip(one_chip, params_mod.abstract_tree(
+        blocks.moe_spec(cfg), cfg.pdtype))
+    x = _sds(one_chip, (64, positions, cfg.d_model), jnp.bfloat16)
+    hlo = jax.jit(lambda p, x: blocks.moe_apply(p, x, cfg, None, None)).lower(
+        params, x).compile().as_text()
+    grouped = 64 * positions > blocks.DENSE_TOKENS
+    assert ("ragged" in hlo) == grouped
+    assert ("tpu_custom_call" in hlo) == grouped
+
+
+def _unfused(hlo: str, shape: str) -> list:
+    """Instructions of ``hlo`` outside any fusion that make a new buffer of
+    ``shape`` (a ``dtype[dims]`` regex): not a parameter, a tuple element
+    or a bitcast of one."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", hlo))
+    comp, out = None, []
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        made = re.match(r"^\s*(?:ROOT )?%[\w.\-]+ = (" + shape
+                        + r")\{[^}]*\} ([\w\-]+)\(", line)
+        if made and comp not in fused and made.group(2) not in (
+                "parameter", "get-tuple-element", "bitcast"):
+            out.append(line.strip())
+    return out
+
+
+def test_decode_step_kimi_vl_fits_one_chip(one_chip):
+    """The served decode step of ``kimi_vl_a3b.stream`` (the dense layer + 8
+    MoE layers, 64 requests, a 256-position latent cache) compiles for one
+    v5e and, with its 10.9 GB of weights, fits the chip's 16 GB. The MoE
+    layers are one scan over stacked weights, and the step reads each
+    layer's experts where they lie: no expert matrix is copied out of the
+    stack (only prefill's grouped-matmul kernel takes such a copy)."""
+    import dataclasses
+
+    from repro import configs
+    from repro.models import lm
+    from repro.serving import ServingEngine
+    cfg = dataclasses.replace(configs.get_arch("kimi-vl-a3b"), num_layers=9)
+    params = _on_chip(one_chip, lm.abstract_params(cfg))
+    eng = ServingEngine(cfg, None, max_len=256)
+    prompts = _sds(one_chip, (64, 128), jnp.int32)
+    state = jax.eval_shape(eng._prefill, params, prompts, None, None, None,
+                           new_tokens=128)[0]
+    compiled = eng._decode.lower(params, _on_chip(one_chip, state)).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 10.9e9 < used < 16e9
+    assert _unfused(compiled.as_text(),
+                    r"bf16\[64,(?:2048,1408|1408,2048)\]") == []
+    pre = eng._prefill.lower(params, prompts, None, None, None,
+                             new_tokens=128).compile().as_text()
+    assert _unfused(pre, r"bf16\[64,(?:2048,1408|1408,2048)\]")
